@@ -298,6 +298,8 @@ def main(argv=None) -> int:
     srv.set_defaults(fn=run_server)
 
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     return args.fn(args)
 
 

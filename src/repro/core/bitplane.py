@@ -156,6 +156,31 @@ def site_randoms(seed, n_rows: int, n_cols: int, offset) -> jax.Array:
     return jnp.stack(r, axis=-1).reshape(n_rows, n_cols)
 
 
+def lane_draws(group, lane, offset, k0, k1) -> jax.Array:
+    """The :func:`site_randoms` draw of each site, from its Philox
+    ``group`` (site // 4) and ``lane`` (site % 4) planes.
+
+    Every site runs the call of its group and keeps its own lane: four
+    times the Philox work of :func:`site_randoms`, the same bits, and no
+    interleave of the four lanes into a minor dimension of 4, which
+    Mosaic lays out badly.  The kernels draw this way.
+    """
+    zero = jnp.zeros_like(group)
+    l0, l1, l2, l3 = crng.philox4x32(offset, zero, group, zero, k0, k1)
+    return jnp.where(lane == 0, l0,
+                     jnp.where(lane == 1, l1,
+                               jnp.where(lane == 2, l2, l3)))
+
+
+def site_groups(row0, shape):
+    """uint32 (group, lane) planes of a row block at plane row ``row0``
+    of a ``shape[1]``-wide plane (the keying of :func:`lane_draws`)."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    group = (rows * (shape[1] // 4) + (cols >> 2)).astype(jnp.uint32)
+    return group, (cols & 3).astype(jnp.uint32)
+
+
 # ---------------------------------------------------------------------------
 # bit-parallel Metropolis accept
 # ---------------------------------------------------------------------------
@@ -228,10 +253,15 @@ def run_sweeps_bitplane(black_words, white_words, inv_temp, n_sweeps: int,
 def replica_observables(black_words, white_words) -> dict:
     """{"m": (32,), "e": (32,)} -- one value per replica lattice.
 
-    Measurement path, not hot path: unpacks to the (32, N, M) replica
-    stack and vmaps the layout-independent full-lattice observables, so
-    each entry is bit-identical to measuring that replica's lattice alone.
+    Measurement path, not hot path: extracts one replica lattice at a
+    time (``lax.map``) and applies the layout-independent full-lattice
+    observables, so each entry is bit-identical to measuring that
+    replica's lattice alone.  One at a time, because the float32 temporaries
+    of all 32 at once (16 GB at 8192^2) do not fit a chip.
     """
-    fulls = unpack_lattices(black_words, white_words)
-    return {"m": jax.vmap(obs.magnetization_full)(fulls),
-            "e": jax.vmap(obs.energy_per_spin_full)(fulls)}
+    def one(r):
+        full = replica_lattice(black_words, white_words, r)
+        return obs.magnetization_full(full), obs.energy_per_spin_full(full)
+
+    m, e = jax.lax.map(one, jnp.arange(N_REPLICAS, dtype=jnp.uint32))
+    return {"m": m, "e": e}

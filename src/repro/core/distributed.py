@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from . import metropolis as metro
 from . import rng as crng
 
@@ -53,7 +52,7 @@ def ring_shift(x: jax.Array, axis_names: Sequence[str], shift: int):
     names = list(axis_names)
 
     def perm(axis, val):
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         pairs = [((i - shift) % n, i) for i in range(n)]
         return jax.lax.ppermute(val, axis, pairs)
 
@@ -61,7 +60,7 @@ def ring_shift(x: jax.Array, axis_names: Sequence[str], shift: int):
     # positions that wrapped on the k-th axis also need the (k-1)-th hop
     for k in range(len(names) - 1, 0, -1):
         idx = jax.lax.axis_index(names[k])
-        n = compat.axis_size(names[k])
+        n = jax.lax.axis_size(names[k])
         at_wrap = (idx == 0) if shift == +1 else (idx == n - 1)
         cross = perm(names[k - 1], out)
         out = jnp.where(at_wrap, cross, out)
@@ -138,7 +137,7 @@ def _global_positions(shape, row_axes, col_axes):
     def multi_index(axes):
         idx = jnp.int32(0)
         for a in axes:
-            idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
 
     r0 = multi_index(row_axes) * n_loc
@@ -203,7 +202,7 @@ def make_ising_step(mesh, *, n: int, m: int, seed: int = 0,
     sharding = jax.sharding.NamedSharding(mesh, spec)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, P(), P()),
         out_specs=(spec, spec),
         check_vma=False)
@@ -259,7 +258,7 @@ def make_packed_ising_step(mesh, *, n: int, m: int, seed: int = 0,
             flip = flip | ((draws[k] < t).astype(jnp.uint32) << sh)
         return target ^ flip
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, P(), P()),
                        out_specs=(spec, spec), check_vma=False)
     def sweeps(black, white, inv_temp, sweep0):
@@ -353,7 +352,7 @@ def make_bitplane_ising_step(mesh, *, n: int, m: int, seed: int = 0,
         return target ^ bp.flip_word_from_classes(target, counts, draws,
                                                   thresholds)
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, P(), P()),
                        out_specs=(spec, spec), check_vma=False)
     def sweeps(black, white, inv_temp, sweep0):
@@ -381,7 +380,7 @@ def magnetization_dist(mesh, row_axes=None, col_axes=None):
     col_axes = tuple(col_axes if col_axes is not None else names[-1:])
     spec = P(row_axes, col_axes)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(spec, spec),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec),
                        out_specs=P(), check_vma=False)
     def _mag(black, white):
         s = black.astype(jnp.float32).sum() + white.astype(jnp.float32).sum()

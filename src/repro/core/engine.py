@@ -231,12 +231,15 @@ class CounterEngine(Engine):
         #: and live traces can never disagree about the tier
         self.resident_attrs: dict = {}
         if self.resident_family is not None:
-            from repro.kernels.resident import (decision_attrs,
-                                                plan_resident)
-            self.resident_plan = plan_resident(self.resident_family,
-                                               config.n, config.m)
-            self.resident_attrs = decision_attrs(self.resident_family,
-                                                 config.n, config.m)
+            from repro.kernels import resident
+            self.resident_plan = resident.plan_resident(
+                self.resident_family, config.n, config.m)
+            self.resident_attrs = resident.decision_attrs(
+                self.resident_family, config.n, config.m)
+            #: row-block height of the per-half-sweep kernels
+            self.block_rows = resident.block_plan(
+                self.resident_family, config.n, config.m).block_rows
+            self.interpret = resident.interpret_mode()
 
     def color_update(self, target, op, inv_temp, is_black, seed, offset,
                      ctx=None):
@@ -337,19 +340,6 @@ class CounterEngine(Engine):
         return degrade.run_dispatch(attempt, engine=self)
 
 
-def _even_block_rows(n: int, cap: int = 256) -> int:
-    """Largest even row-block count <= ``cap`` dividing the plane height
-    ``n`` -- the Pallas row-block engines need even blocks so checkerboard
-    parity is uniform within a block."""
-    best = 0
-    for d in range(2, min(n, cap) + 1, 2):
-        if n % d == 0:
-            best = d
-    assert best, f"Pallas row-block engines need an even lattice height," \
-        f" got {n}"
-    return best
-
-
 # ---------------------------------------------------------------------------
 # compact color-plane engines (basic / basic_philox / stencil_pallas)
 # ---------------------------------------------------------------------------
@@ -401,7 +391,8 @@ class BasicPhiloxEngine(_PlanesEngine, CounterEngine):
 
 @register
 class StencilPallasEngine(_PlanesEngine, CounterEngine):
-    """Fused Pallas stencil kernel (DESIGN.md S6.2); interpret-mode on CPU.
+    """Fused Pallas stencil kernel (DESIGN.md S6.2); interpret mode off
+    a TPU (``kernels.resident.interpret_mode``).
 
     Philox is keyed on the global (row, col) index, so this engine is
     bit-for-bit identical to ``basic_philox`` -- the kernel's pure-jnp
@@ -411,11 +402,6 @@ class StencilPallasEngine(_PlanesEngine, CounterEngine):
     name = "stencil_pallas"
     resident_family = "stencil"
     dist_factory = "basic"  # bit-for-bit the basic_philox stream
-
-    def __init__(self, config):
-        super().__init__(config)
-        self.block_rows = _even_block_rows(config.n)
-        self.interpret = jax.default_backend() != "tpu"
 
     def color_update(self, target, op, inv_temp, is_black, seed, offset,
                      ctx=None):
@@ -493,11 +479,6 @@ class MultispinPallasEngine(MultispinEngine):
     name = "multispin_pallas"
     resident_family = "multispin"
 
-    def __init__(self, config):
-        super().__init__(config)
-        self.block_rows = _even_block_rows(config.n)
-        self.interpret = jax.default_backend() != "tpu"
-
     def color_update(self, target, op, inv_temp, is_black, seed, offset,
                      ctx=None):
         from repro.kernels.multispin.multispin import multispin_update
@@ -552,11 +533,19 @@ class BitplaneEngine(CounterEngine):
         def init_one(k):
             return lat.init_lattice(k, cfg.n, cfg.m, p_up=cfg.init_p_up)
 
-        r0 = init_one(key)
-        keys = jax.vmap(lambda r: jax.random.fold_in(key, r))(
-            jnp.arange(1, bp.N_REPLICAS))
-        rest = jax.vmap(init_one)(keys)
-        return bp.pack_lattices(jnp.concatenate([r0[None], rest], axis=0))
+        # one jitted program: XLA fuses each replica's uniforms into its
+        # int8 spins, where eager ops would hold 31 float32 lattices at
+        # once (7.75 GB at 8192^2, more than a 16 GB chip has left)
+        @jax.jit
+        def build(key):
+            r0 = init_one(key)
+            keys = jax.vmap(lambda r: jax.random.fold_in(key, r))(
+                jnp.arange(1, bp.N_REPLICAS))
+            rest = jax.vmap(init_one)(keys)
+            return bp.pack_lattices(
+                jnp.concatenate([r0[None], rest], axis=0))
+
+        return build(key)
 
     def from_full(self, full):
         black, white = lat.split_checkerboard(full)
@@ -609,11 +598,6 @@ class BitplanePallasEngine(BitplaneEngine):
 
     name = "bitplane_pallas"
     resident_family = "bitplane"
-
-    def __init__(self, config):
-        super().__init__(config)
-        self.block_rows = _even_block_rows(config.n)
-        self.interpret = jax.default_backend() != "tpu"
 
     def color_update(self, target, op, inv_temp, is_black, seed, offset,
                      ctx=None):

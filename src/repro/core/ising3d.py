@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 
 T_CRITICAL_3D = 4.5115  # numerically known, J = 1
 
@@ -95,7 +94,7 @@ def make_ising3d_step(mesh, *, n: int, seed: int = 0, n_sweeps: int = 1,
         # global-position-keyed philox (grid independence, as in 2D)
         r0 = jnp.int32(0)
         for a in slab_axes:
-            r0 = r0 * compat.axis_size(a) + jax.lax.axis_index(a)
+            r0 = r0 * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         gi = (r0 * nl + row_i) * full.shape[1] * full.shape[2] \
             + jax.lax.broadcasted_iota(jnp.int32, full.shape, 1) \
             * full.shape[2] \
@@ -110,7 +109,7 @@ def make_ising3d_step(mesh, *, n: int, seed: int = 0, n_sweeps: int = 1,
         flip = (((ii + jj + kk) % 2) == color) & (u < acc)
         return jnp.where(flip, -x, x).astype(full.dtype)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(spec, P(), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, P(), P()),
                        out_specs=spec, check_vma=False)
     def sweeps(full, inv_temp, sweep0):
         def body(i, f):

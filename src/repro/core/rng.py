@@ -126,6 +126,18 @@ def uniforms(seed, sequence, offset, n_lanes: int = 4):
     return tuple(u32_to_uniform(r) for r in (r0, r1, r2, r3))[:n_lanes]
 
 
+def u32_to_float(bits):
+    """uint32 -> float32, bit for bit ``bits.astype(float32)``.
+
+    Mosaic has no uint32 -> float32 cast, so the word is split into two
+    16-bit halves that int32 -> float32 converts exactly; ``hi * 2^16``
+    is exact too, so the one rounding is in the final add, the same
+    round-to-nearest-even the direct cast does."""
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & _LO16).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
 def u32_to_uniform(bits):
     """uint32 -> float32 uniform in [0, 1) (multiply by 2^-32)."""
-    return bits.astype(jnp.float32) * jnp.float32(2.3283064365386963e-10)
+    return u32_to_float(bits) * jnp.float32(2.3283064365386963e-10)

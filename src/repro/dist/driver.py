@@ -37,8 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.core.distributed import ring_shift
+from repro.kernels import resident
 
 from . import kernels as dk
 from .planner import ShardPlan
@@ -48,7 +48,7 @@ def _multi_index(axes):
     """Linear device index over a product of mesh axes (msb first)."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -90,7 +90,7 @@ def make_resident_step(mesh, plan: ShardPlan, *, seed: int = 0,
         f"{rows_devs}x{cols_devs}")
     assert n_sweeps >= 1, n_sweeps
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = resident.interpret_mode()
 
     fam, h, k = plan.family, plan.halo, plan.k
     width = plan.width
@@ -110,7 +110,7 @@ def make_resident_step(mesh, plan: ShardPlan, *, seed: int = 0,
             width)[None, :]
         return rows, cols
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, P(), P()),
                        out_specs=(spec, spec), check_vma=False)
     def _sweeps(black, white, inv_temp, start):

@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
+from repro.kernels import resident as vmem
 from repro.kernels.bitplane import resident as bp_res
 from repro.kernels.multispin import resident as ms_res
 from repro.kernels.stencil import resident as st_res
@@ -83,6 +84,7 @@ def stencil_shard_sweeps(black, white, inv_temp, gidx, *,
                    jax.ShapeDtypeStruct(white.shape, white.dtype)),
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(beta, seeds, gidx, black, white)
 
 
@@ -114,6 +116,7 @@ def multispin_shard_sweeps(black, white, thresholds, widx, *,
                    jax.ShapeDtypeStruct(white.shape, white.dtype)),
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, widx, black, white)
 
 
@@ -146,4 +149,5 @@ def bitplane_shard_sweeps(black, white, thresholds, gidx, lane, *,
                    jax.ShapeDtypeStruct(white.shape, white.dtype)),
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, gidx, lane, black, white)

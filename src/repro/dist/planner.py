@@ -14,10 +14,11 @@ Constraints (DESIGN.md S15 decision table):
   halo wider than the shard itself would need multi-hop gathers the
   driver does not implement (and that would be slower than the
   per-half-sweep fallback anyway);
-* **VMEM fit**: the extended working set -- extended cells times the
-  family's S9 temporaries multiplier, plus the uint32 global-index
-  planes the kernel needs for Philox keying -- must fit the same
-  8 MiB budget the single-device planner uses;
+* **VMEM fit**: the extended working set -- padded extended cells
+  times the family's S9 resident bytes per cell, plus the uint32
+  global-index planes the kernel needs for Philox keying -- must fit
+  the same ``VMEM_LIMIT_BYTES`` the single-device planner uses (and
+  every kernel is compiled with);
 * **overlap cap**: the extended area may be at most
   :data:`MAX_OVERLAP` times the owned area.  The halo cells are
   *redundantly* swept every half-sweep (that is the double-halo
@@ -42,7 +43,7 @@ import dataclasses
 import math
 from typing import Optional
 
-from repro.kernels.resident import _FAMILIES, VMEM_BUDGET_BYTES
+from repro.kernels import resident
 from repro.resilience import degrade
 
 #: default cap on sweeps-per-exchange: past this the redundant halo
@@ -53,16 +54,12 @@ K_CAP: int = 4
 #: sweep work disqualifies a k (see module docstring)
 MAX_OVERLAP: float = 2.0
 
-#: family -> (cells per plane row given lattice m, bytes per cell,
-#: uint32 index planes the kernel needs for global Philox keying)
+#: family -> uint32 index planes the kernel needs for global Philox
+#: keying: gidx (stencil), widx (multispin), group + lane (bitplane).
 #: Cell = one element of the compact color plane: an int8 site
 #: (stencil), a uint32 8-spin word (multispin, m/16 per row), or a
 #: uint32 32-replica word (bitplane, m/2 per row).
-_GEOMETRY = {
-    "stencil": (lambda m: m // 2, 1, 1),      # gidx
-    "multispin": (lambda m: m // 16, 4, 1),   # widx
-    "bitplane": (lambda m: m // 2, 4, 2),     # group + lane
-}
+_INDEX_PLANES = {"stencil": 1, "multispin": 1, "bitplane": 2}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +82,11 @@ class ShardPlan:
     @property
     def width(self) -> int:
         """Global plane cells per row (family packing units)."""
-        return _GEOMETRY[self.family][0](self.m)
+        return resident.plane_width(self.family, self.m)
 
     @property
     def cell_bytes(self) -> int:
-        return _GEOMETRY[self.family][1]
+        return resident._FAMILIES[self.family].cell_bytes
 
     def exchanges(self, n_sweeps: int) -> int:
         """Halo exchange events one dispatch of ``n_sweeps`` performs:
@@ -113,15 +110,16 @@ def shard_working_set_bytes(family: str, n_loc: int, w_loc: int,
                             halo: int) -> int:
     """Modeled per-shard VMEM peak of the extended-plane kernel.
 
-    Same temporaries model as the single-device planner (the S9
-    multipliers in ``kernels/resident._FAMILIES``) applied to the
-    extended cell count, plus one uint32 global-index plane per
-    index input the kernel takes (Philox keying, S15).
+    Same model as the single-device planner (the S9 resident bytes per
+    padded cell in ``kernels/resident._FAMILIES``) applied to the
+    extended plane, plus one uint32 global-index plane per index input
+    the kernel takes (Philox keying, S15).
     """
-    _, mult = _FAMILIES[family]
-    _, cell_bytes, n_idx = _GEOMETRY[family]
-    ext = (n_loc + 2 * halo) * (w_loc + 2 * halo)
-    return int(ext * (cell_bytes * mult + 4 * n_idx))
+    rows, width = n_loc + 2 * halo, w_loc + 2 * halo
+    cells = resident.padded_cells(family, rows, width)
+    idx_cells = resident.padded_cells(family, rows, width, tile_rows=8)
+    return (cells * resident._FAMILIES[family].resident_bytes
+            + 4 * _INDEX_PLANES[family] * idx_cells)
 
 
 def plan_shard_resident(family: str, n: int, m: int, rows_devs: int,
@@ -139,13 +137,13 @@ def plan_shard_resident(family: str, n: int, m: int, rows_devs: int,
     :data:`MAX_OVERLAP` (tests pin k on small shards with it; the
     driver is exact at ANY feasible k, the cap is pure perf policy).
     """
-    if family not in _GEOMETRY:
+    if family not in _INDEX_PLANES:
         raise ValueError(f"unknown resident family {family!r}; "
-                         f"known: {sorted(_GEOMETRY)}")
-    budget = VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
+                         f"known: {sorted(_INDEX_PLANES)}")
+    budget = (resident.VMEM_LIMIT_BYTES if budget_bytes is None
+              else budget_bytes)
     overlap = MAX_OVERLAP if max_overlap is None else max_overlap
-    width_of, _, _ = _GEOMETRY[family]
-    width = width_of(m)
+    width = resident.plane_width(family, m)
     if degrade.demotion_reason(family, n, m) is not None:
         return None
     if n % rows_devs or width % cols_devs:
@@ -178,7 +176,8 @@ def shard_decision_attrs(family: str, n: int, m: int, rows_devs: int,
     the single rendering shared by ``--dry-run`` (``describe``), the
     sharded dispatch span attributes, and tests, mirroring the
     single-device ``kernels.resident.decision_attrs`` contract."""
-    budget = VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
+    budget = (resident.VMEM_LIMIT_BYTES if budget_bytes is None
+              else budget_bytes)
     plan = plan_shard_resident(family, n, m, rows_devs, cols_devs,
                                budget_bytes=budget, k_cap=k_cap)
     attrs = {"family": family, "grid": f"{rows_devs}x{cols_devs}",
@@ -191,12 +190,11 @@ def shard_decision_attrs(family: str, n: int, m: int, rows_devs: int,
                      halo_bytes_per_exchange=plan.halo_bytes_per_exchange)
         return attrs
     demoted = degrade.demotion_reason(family, n, m)
-    width_of, _, _ = _GEOMETRY[family]
     if demoted is not None:
         attrs["demoted"] = True
         attrs["reason"] = (f"demoted to per-half-sweep distributed "
                            f"tier: {demoted}")
-    elif n % rows_devs or width_of(m) % cols_devs \
+    elif n % rows_devs or resident.plane_width(family, m) % cols_devs \
             or (n // rows_devs) % 2:
         attrs["reason"] = ("lattice does not tile the device grid "
                            "evenly: per-half-sweep distributed tier")

@@ -20,14 +20,8 @@ Two consumers share :func:`measure_rows`:
   filtered (``meta.only = "dist"``) so the gate skips the non-dist
   baseline rows.
 """
-import os
-
-# must precede any jax backend init: the weak-scaling axis needs
-# multiple (forced host) devices
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
 import argparse
+import os
 import time
 from typing import Dict, Iterable, List
 
@@ -128,6 +122,10 @@ def main(argv=None) -> int:
     devices = [int(d) for d in args.devices.split(",") if d]
     if not devices or any(d < 1 for d in devices):
         ap.error(f"--devices must be positive ints, got {args.devices!r}")
+    # before JAX's backend starts: on a CPU host the weak-scaling axis
+    # needs forced host devices (the flag is inert on accelerators)
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
     import jax
     from repro.analysis.recorder import RunRecorder

@@ -25,6 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitplane as bpc
+from repro.kernels import resident as vmem
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -49,19 +50,9 @@ def _kernel(seeds_ref, thr_ref, target_ref, op_m1_ref, op_0_ref,
 
     # one shared draw per site: counter = (offset, 0, site//4, 0), lane =
     # site%4 -- identical (group, lane) math to core.bitplane.site_randoms
-    k0 = seeds_ref[0]
-    k1 = seeds_ref[1]
-    offset = seeds_ref[2]
-    w = op.shape[1]
-    i = pl.program_id(0)
-    gshape = (block_rows, w // 4)
-    rows = (i * block_rows
-            + jax.lax.broadcasted_iota(jnp.int32, gshape, 0))
-    cols = jax.lax.broadcasted_iota(jnp.int32, gshape, 1)
-    g = (rows * (w // 4) + cols).astype(jnp.uint32)
-    zero = jnp.zeros_like(g)
-    lanes = bpc.crng.philox4x32(offset, zero, g, zero, k0, k1)
-    draws = jnp.stack(lanes, axis=-1).reshape(block_rows, w)
+    group, lane = bpc.site_groups(pl.program_id(0) * block_rows, op.shape)
+    draws = bpc.lane_draws(group, lane, seeds_ref[2], seeds_ref[0],
+                           seeds_ref[1])
 
     target = target_ref[...]
     thr = [thr_ref[c] for c in range(10)]  # SMEM scalar reads, no gather
@@ -105,4 +96,5 @@ def bitplane_update(target_words, op_words, inv_temp, *, is_black: bool,
         out_shape=jax.ShapeDtypeStruct(target_words.shape,
                                        target_words.dtype),
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, target_words, op_words, op_words, op_words)

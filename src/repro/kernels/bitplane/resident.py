@@ -24,6 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitplane as bpc
 from repro.core import rng as crng
+from repro.kernels import resident as vmem
 
 
 def _half_sweep(target, op, is_black: bool, thr, k0, k1, offset,
@@ -33,9 +34,9 @@ def _half_sweep(target, op, is_black: bool, thr, k0, k1, offset,
     ``gidx``/``lane`` override the shared-draw keying with precomputed
     uint32 global (site // 4, site % 4) planes -- the sharded resident
     tier (``repro.dist``) uses them because its halo-extended shard
-    columns are neither 0-based nor 4-aligned, so the draw is made
-    per site with a lane select (same (group, lane) math as
-    ``core.bitplane.site_randoms``, 4x the Philox work, same bits)."""
+    columns are neither 0-based nor 4-aligned.  Either way each site
+    draws through ``core.bitplane.lane_draws`` (same (group, lane) math
+    as ``core.bitplane.site_randoms``, same bits)."""
     up = jnp.concatenate([op[-1:, :], op[:-1, :]], axis=0)
     down = jnp.concatenate([op[1:, :], op[:1, :]], axis=0)
     nxt = jnp.concatenate([op[:, 1:], op[:, :1]], axis=1)
@@ -49,21 +50,8 @@ def _half_sweep(target, op, is_black: bool, thr, k0, k1, offset,
     counts = bpc.bit_count_neighbors(up, down, op, side)
 
     if gidx is None:
-        n, w = op.shape
-        gshape = (n, w // 4)
-        rows = jax.lax.broadcasted_iota(jnp.int32, gshape, 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, gshape, 1)
-        g = (rows * (w // 4) + cols).astype(jnp.uint32)
-        zero = jnp.zeros_like(g)
-        lanes = crng.philox4x32(offset, zero, g, zero, k0, k1)
-        draws = jnp.stack(lanes, axis=-1).reshape(n, w)
-    else:
-        zero = jnp.zeros_like(gidx)
-        l0, l1, l2, l3 = crng.philox4x32(offset, zero, gidx, zero,
-                                         k0, k1)
-        draws = jnp.where(lane == 0, l0,
-                          jnp.where(lane == 1, l1,
-                                    jnp.where(lane == 2, l2, l3)))
+        gidx, lane = bpc.site_groups(0, op.shape)
+    draws = bpc.lane_draws(gidx, lane, offset, k0, k1)
     return target ^ bpc.flip_word_from_classes(target, counts, draws, thr)
 
 
@@ -122,4 +110,5 @@ def bitplane_sweeps_resident(black_words, white_words, inv_temp, *,
                                         white_words.dtype)),
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, black_words, white_words)

@@ -22,8 +22,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import rng as crng
 from repro.core import lattice as lat
+from repro.core import rng as crng
+from repro.kernels import resident as vmem
 
 DEFAULT_BLOCK_ROWS = 256
 _NIB = lat.NIBBLE_BITS
@@ -50,8 +51,9 @@ def _kernel(seeds_ref, thr_ref, target_ref, op_m1_ref, op_0_ref,
     nn_words = up + down + op + side          # 3 packed adds / 8 spins
 
     target = target_ref[...]
-    seed = seeds_ref[0]
-    offset = seeds_ref[1]
+    k0 = seeds_ref[0]
+    k1 = seeds_ref[1]
+    offset = seeds_ref[2]
     i = pl.program_id(0)
     w = op.shape[1]
     rows = (i * block_rows
@@ -59,10 +61,9 @@ def _kernel(seeds_ref, thr_ref, target_ref, op_m1_ref, op_0_ref,
     cols = jax.lax.broadcasted_iota(jnp.int32, op.shape, 1)
     widx = (rows * w + cols).astype(jnp.uint32)
     zero = jnp.zeros_like(widx)
-    lo = crng.philox4x32(np.uint32(2) * offset, zero, widx, zero,
-                         seed, jnp.uint32(0))
+    lo = crng.philox4x32(np.uint32(2) * offset, zero, widx, zero, k0, k1)
     hi = crng.philox4x32(np.uint32(2) * offset + np.uint32(1), zero, widx,
-                         zero, seed, jnp.uint32(0))
+                         zero, k0, k1)
     draws = lo + hi  # 8 uint32 per word
 
     # integer-threshold accept (H1.6): the 10 uint32 thresholds live in
@@ -101,19 +102,17 @@ def multispin_update(target_words, op_words, inv_temp, *, is_black: bool,
 
     if thresholds is None:
         thresholds = ms.acceptance_thresholds(inv_temp)
-    # seed may be a python int or a traced uint32 scalar (ensemble vmap,
-    # demoted-fallback dispatch); mask in python only when it IS python
-    if isinstance(seed, (int, np.integer)):
-        seed = seed & 0xFFFFFFFF
-    seeds = jnp.stack([jnp.asarray(seed).astype(jnp.uint32),
-                       jnp.asarray(offset).astype(jnp.uint32)])
+    # seed may be a python int (full 64-bit key, like the oracle's
+    # word_randoms) or a traced uint32 scalar (ensemble vmap)
+    k0, k1 = crng.seed_keys(seed)
+    seeds = jnp.stack([k0, k1, jnp.asarray(offset).astype(jnp.uint32)])
 
     row_spec = pl.BlockSpec((block_rows, w), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_kernel, is_black=is_black, block_rows=block_rows),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # seed/offset
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # (k0, k1, offset)
             pl.BlockSpec(memory_space=pltpu.SMEM),   # acceptance thresholds
             row_spec,
             pl.BlockSpec((block_rows, w), lambda i: ((i - 1) % nb, 0)),
@@ -124,4 +123,5 @@ def multispin_update(target_words, op_words, inv_temp, *, is_black: bool,
         out_shape=jax.ShapeDtypeStruct(target_words.shape,
                                        target_words.dtype),
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, target_words, op_words, op_words, op_words)

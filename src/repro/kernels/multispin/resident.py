@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lattice as lat
 from repro.core import rng as crng
+from repro.kernels import resident as vmem
 
 _NIB = lat.NIBBLE_BITS
 
@@ -133,4 +134,5 @@ def multispin_sweeps_resident(black_words, white_words, inv_temp, *,
                                         white_words.dtype)),
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(seeds, thresholds, black_words, white_words)

@@ -12,9 +12,10 @@ sweep loop uses, so the output is bit-for-bit ``n_sweeps`` applications
 of the per-half-sweep oracle (``basic_philox`` -- tested in
 tests/test_resident.py) and checkpoints/restarts keep their stream.
 
-Neighbor shifts are slice-concat (pad+slice form, H1.4) and the
-neighbor sums stay int8 (|sum| <= 4, H1.5), matching
-``core.metropolis.neighbor_sums``.  Plane inputs are aliased to the
+Neighbor shifts are slice-concat (pad+slice form, H1.4); the int8
+planes widen to int32 for the neighbor sums (Mosaic has no int8 vector
+arithmetic; |sum| <= 4, so the values are ``core.metropolis``'s int8
+sums, H1.5).  Plane inputs are aliased to the
 outputs (``input_output_aliases``), so together with the donated jit
 wrappers (H1.8) the planes never hold two HBM copies.
 """
@@ -28,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
+from repro.kernels import resident as vmem
 
 
 def _half_sweep(target, op, inv_temp, is_black: bool, k0, k1, offset,
@@ -35,14 +37,17 @@ def _half_sweep(target, op, inv_temp, is_black: bool, k0, k1, offset,
     """One color half-sweep on whole VMEM-resident planes.
 
     Identical math (and float op order) to ``stencil.py``'s blocked
-    kernel / ``core.metropolis.update_color_philox``: int8 neighbor
-    sums, global (row, col) Philox keying, ``exp(-2 beta nn s)`` accept.
+    kernel / ``core.metropolis.update_color_philox``: the same neighbor
+    sums (widened to int32), global (row, col) Philox keying,
+    ``exp(-2 beta nn s)`` accept.
 
     ``gidx`` overrides the Philox site keying with a precomputed uint32
     global-index plane -- the sharded resident tier (``repro.dist``)
     passes the TRUE global positions of its halo-extended shard, so the
     draws match this kernel's own iota keying on the full lattice.
     """
+    op = op.astype(jnp.int32)
+    t = target.astype(jnp.int32)
     up = jnp.concatenate([op[-1:, :], op[:-1, :]], axis=0)
     down = jnp.concatenate([op[1:, :], op[:1, :]], axis=0)
     plus = jnp.concatenate([op[:, 1:], op[:, :1]], axis=1)
@@ -52,7 +57,7 @@ def _half_sweep(target, op, inv_temp, is_black: bool, k0, k1, offset,
         side = jnp.where(parity == 1, plus, minus)
     else:
         side = jnp.where(parity == 1, minus, plus)
-    nn = up + down + op + side  # int8 stays int8 (H1.5)
+    nn = up + down + op + side
 
     if gidx is None:
         h = op.shape[1]
@@ -63,8 +68,8 @@ def _half_sweep(target, op, inv_temp, is_black: bool, k0, k1, offset,
     bits = crng.philox4x32(offset, zero, gidx, zero, k0, k1)[0]
     u = crng.u32_to_uniform(bits)
     acc = jnp.exp(-2.0 * inv_temp * nn.astype(jnp.float32)
-                  * target.astype(jnp.float32))
-    return jnp.where(u < acc, -target, target).astype(target.dtype)
+                  * t.astype(jnp.float32))
+    return jnp.where(u < acc, -t, t).astype(target.dtype)
 
 
 def _kernel(beta_ref, seeds_ref, black_ref, white_ref, black_out,
@@ -117,4 +122,5 @@ def stencil_sweeps_resident(black, white, inv_temp, *, n_sweeps: int,
                    jax.ShapeDtypeStruct(white.shape, white.dtype)),
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(beta, seeds, black, white)

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
+from repro.kernels import resident as vmem
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -40,19 +41,19 @@ def _kernel(beta_ref, seeds_ref, target_ref, op_m1_ref, op_0_ref, op_p1_ref,
             out_ref, *, is_black: bool, block_rows: int, use_philox: bool,
             uniforms_ref=None):
     inv_temp = beta_ref[0]
-    # neighbor sums stay in the plane dtype (int8: |sum| <= 4, H1.5) --
-    # no int32 widening of the working set; the accept converts to
-    # float32 exactly where the int32 path did, so flips are bit-identical
-    op = op_0_ref[...]
-    up_row = op_m1_ref[...][-1:, :]
-    down_row = op_p1_ref[...][:1, :]
+    # neighbour sums in int32: Mosaic has no int8 vector arithmetic.
+    # |sum| <= 4, so the values, the float32 accept and the flips are
+    # those of the int8 oracle (H1.5)
+    op = op_0_ref[...].astype(jnp.int32)
+    up_row = op_m1_ref[...].astype(jnp.int32)[-1:, :]
+    down_row = op_p1_ref[...].astype(jnp.int32)[:1, :]
     up = jnp.concatenate([up_row, op[:-1, :]], axis=0)
     down = jnp.concatenate([op[1:, :], down_row], axis=0)
     parity = (jax.lax.broadcasted_iota(jnp.int32, op.shape, 0)
               % 2)  # block height is even => local parity == global parity
     nn = up + down + op + _side(op, parity, is_black)
 
-    t = target_ref[...]
+    t = target_ref[...].astype(jnp.int32)
     if use_philox:
         k0 = seeds_ref[0]
         k1 = seeds_ref[1]
@@ -122,4 +123,5 @@ def stencil_update(target, op_plane, inv_temp, *, is_black: bool,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct(target.shape, target.dtype),
         interpret=interpret,
+        compiler_params=vmem.compiler_params(),
     )(*args)

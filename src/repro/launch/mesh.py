@@ -14,14 +14,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh``: pass explicit Auto axis_types on
-    jax >= 0.5 (where AxisType exists), plain mesh on older releases
-    (where every axis is Auto implicitly)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,14 +9,17 @@ classifies failures by *recoverability* (DESIGN.md S13):
   increments the ``resilience.retry`` counter and emits a
   ``resilience.retry`` trace instant.
 * **resident-tier resource exhaustion** (:func:`is_resident_oom` -- an
-  ``XlaRuntimeError``-style message carrying ``RESOURCE_EXHAUSTED``,
-  the class a resident kernel's VMEM working set hits on real
-  hardware) -- the (engine family, lattice) is *demoted* to the
+  ``XlaRuntimeError``-style launch failure carrying
+  ``RESOURCE_EXHAUSTED``) -- the (engine family, lattice) is *demoted* to the
   per-half-sweep fallback tier for the rest of the process and the
   launch retried immediately.  Both tiers draw the same Philox stream
   (tests/test_resident.py), so demotion is invisible in the
   trajectory; it costs one re-JIT and O(k) extra HBM traffic.
-* anything else propagates unchanged.
+* anything else propagates unchanged -- including Mosaic refusing a
+  kernel at compile time (:func:`is_compile_refusal`: a working set over
+  the scoped-VMEM limit also says ``RESOURCE_EXHAUSTED``).  That is a
+  planner bug (``kernels/resident.py``), and running the fallback tier
+  in silence would hide it.
 
 Demotions live in a process-global registry keyed ``(family, n, m)``:
 ``kernels.resident.plan_resident`` and ``decision_attrs`` consult it,
@@ -80,11 +83,21 @@ def is_transient(exc: BaseException) -> bool:
     return any(tok in msg for tok in _TRANSIENT_TOKENS)
 
 
+#: phrases of Mosaic's compile-time scoped-VMEM refusal
+_COMPILE_REFUSAL_TOKENS = ("memory space vmem", "scoped vmem")
+
+
+def is_compile_refusal(exc: BaseException) -> bool:
+    """Mosaic refused to compile a kernel for its VMEM working set."""
+    msg = str(exc).lower()
+    return any(tok in msg for tok in _COMPILE_REFUSAL_TOKENS)
+
+
 def is_resident_oom(exc: BaseException) -> bool:
-    """A resource-exhaustion failure (real XLA OOM or the injected
-    stand-in): recoverable by demoting the resident tier, NOT by
-    retrying the same program."""
-    return "RESOURCE_EXHAUSTED" in str(exc)
+    """A runtime resource-exhaustion failure (real XLA OOM or the
+    injected stand-in): recoverable by demoting the resident tier, NOT
+    by retrying the same program.  A compile refusal is not one."""
+    return "RESOURCE_EXHAUSTED" in str(exc) and not is_compile_refusal(exc)
 
 
 # ---------------------------------------------------------------------------

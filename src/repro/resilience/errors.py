@@ -23,12 +23,12 @@ class TransientDispatchError(ResilienceError):
 
 
 class SimulatedResourceExhausted(ResilienceError):
-    """Injected stand-in for an XLA ``RESOURCE_EXHAUSTED`` failure (the
-    VMEM/OOM class a resident kernel can hit on real hardware).  The
+    """Injected stand-in for an XLA ``RESOURCE_EXHAUSTED`` launch failure
+    (the runtime out-of-memory class a resident dispatch can hit).  The
     message carries the literal ``RESOURCE_EXHAUSTED`` token so the
     classifier treats real and simulated failures identically."""
 
-    def __init__(self, detail: str = "simulated VMEM exhaustion"):
+    def __init__(self, detail: str = "simulated device memory exhaustion"):
         super().__init__(f"RESOURCE_EXHAUSTED: {detail} (injected by "
                          f"repro.resilience.faults)")
 

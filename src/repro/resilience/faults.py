@@ -68,7 +68,7 @@ class FaultPlan:
             self.fired["resident_oom"] = \
                 self.fired.get("resident_oom", 0) + 1
             raise SimulatedResourceExhausted(
-                "resident kernel VMEM working set over budget")
+                "resident kernel launch out of device memory")
         if self.transient_dispatches > 0:
             self.transient_dispatches -= 1
             self.fired["transient_dispatch"] = \

@@ -48,6 +48,8 @@ def main(argv=None) -> int:
                     "(exit 0 done / 3 drained-preempted)")
     add_serve_args(ap)
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     return run_server(args)
 
 

@@ -13,7 +13,8 @@ Two phases, each against a real ``python -m repro serve`` subprocess:
    ``--drain-on-idle``, and assert: every acked job completes, each
    has EXACTLY one ``done`` record (the journal's ``job_table`` raises
    on duplicates), and every digest is bit-identical to a direct
-   in-process ``Session`` run of the same spec;
+   in-process ``Session`` run of the same spec (computed after the last
+   server exits, so this process never holds a chip a server needs);
 
 2. **coalescing** -- on a fresh directory, queue k compatible specs
    behind a blocker job and assert from the journal that all k ran as
@@ -110,16 +111,14 @@ def _journal_records(workdir):
         j.close()
 
 
-def _phase_crash(args) -> None:
+def _phase_crash(args) -> list:
     from .client import ServeClient
     workdir = os.path.join(args.workdir, "crash")
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir, exist_ok=True)
 
     specs = _specs(args)
-    print(f"# [1/2] crash drill: {len(specs)} jobs, computing "
-          f"reference digests in-process", flush=True)
-    refs = _reference_digests(specs, args.sweeps)
+    print(f"# [1/2] crash drill: {len(specs)} jobs", flush=True)
 
     proc = _start_server(args, workdir)
     client = ServeClient(workdir)
@@ -151,18 +150,31 @@ def _phase_crash(args) -> None:
     missing = [j for j in jids if j not in dones]
     if missing:
         raise SystemExit(f"jobs lost across the kill: {missing}")
-    for jid, spec, want in zip(jids, specs, refs):
+    for jid in jids:
         done = dones[jid]
         if done["status"] != "completed":
             raise SystemExit(f"{jid} finished {done['status']}: "
                              f"{done.get('error')}")
-        if done["digest"] != want:
+    print(f"# crash drill: {len(jids)} jobs exactly-once", flush=True)
+    return [(jid, spec, dones[jid]["digest"])
+            for jid, spec in zip(jids, specs)]
+
+
+def _check_digests(args, finished) -> None:
+    """Every crash-drill digest against a direct in-process ``Session``
+    run of the same spec.  Run after the last server has exited: this
+    process touches JAX here and nowhere else, so on a chip it never
+    holds the device while a server needs it."""
+    print("# computing reference digests in-process", flush=True)
+    refs = _reference_digests([spec for _, spec, _ in finished],
+                              args.sweeps)
+    for (jid, spec, got), want in zip(finished, refs):
+        if got != want:
             raise SystemExit(
-                f"{jid} ({spec.engine.name}): digest "
-                f"{done['digest']} != direct-Session reference "
-                f"{want}")
-    print(f"# crash drill OK: {len(jids)} jobs exactly-once, every "
-          f"digest bit-identical to a direct run", flush=True)
+                f"{jid} ({spec.engine.name}): digest {got} != "
+                f"direct-Session reference {want}")
+    print(f"# crash drill OK: every digest bit-identical to a direct "
+          f"run", flush=True)
 
 
 def _phase_coalesce(args) -> None:
@@ -229,8 +241,9 @@ def main(argv=None) -> int:
     ap.add_argument("--timeout", type=float, default=600.0,
                     help="per-wait wall-clock budget (s)")
     args = ap.parse_args(argv)
-    _phase_crash(args)
+    finished = _phase_crash(args)
     _phase_coalesce(args)
+    _check_digests(args, finished)
     print("serve smoke OK: crash safety + coalescing verified")
     return 0
 

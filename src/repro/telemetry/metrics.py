@@ -116,9 +116,11 @@ class Histogram:
         with self._lock:
             if not self.count:
                 return {"count": 0}
+            # sum / count can round one ulp past the extremes (three
+            # equal observations); the mean of the data never does
+            mean = min(max(self.sum / self.count, self.min), self.max)
             return {"count": self.count, "sum": self.sum,
-                    "min": self.min, "max": self.max,
-                    "mean": self.sum / self.count}
+                    "min": self.min, "max": self.max, "mean": mean}
 
     def _reset(self) -> None:
         with self._lock:

@@ -49,14 +49,15 @@ def test_stencil_kernel_uniforms_dtypes(n, m, dtype):
 
 @pytest.mark.parametrize("n,m", SHAPES)
 @pytest.mark.parametrize("is_black", [True, False])
-def test_multispin_kernel(n, m, is_black):
+@pytest.mark.parametrize("seed", [11, (0xABCD << 32) | 11])  # 64-bit key
+def test_multispin_kernel(n, m, is_black, seed):
     full = lat.init_lattice(jax.random.PRNGKey(3), n, m)
     bw, ww = ms.pack_lattice(*lat.split_checkerboard(full))
     t, op = (bw, ww) if is_black else (ww, bw)
     beta = jnp.float32(1 / 2.3)
-    out_k = multispin_update(t, op, beta, is_black=is_black, seed=11,
+    out_k = multispin_update(t, op, beta, is_black=is_black, seed=seed,
                              offset=3, block_rows=8, interpret=True)
-    out_r = multispin_update_ref(t, op, beta, is_black=is_black, seed=11,
+    out_r = multispin_update_ref(t, op, beta, is_black=is_black, seed=seed,
                                  offset=3)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 

@@ -1,6 +1,8 @@
 """Resident-sweep tier (DESIGN.md S9): bit-exactness vs the
 per-half-sweep oracles at several k and lattice sizes, the VMEM planner
 fallback boundary (both sides), and the registry/measurement routing."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -85,15 +87,41 @@ def test_resident_64bit_seed_matches_oracle():
 
 @pytest.mark.parametrize("family", ["stencil", "multispin", "bitplane"])
 def test_planner_boundary_both_sides(family):
-    """max_square_lattice is the boundary: n fits, n+2 falls back."""
+    """max_square_lattice is the boundary: n fits, the next lattice the
+    engine accepts falls back."""
     n = resident.max_square_lattice(family)
-    assert n > 0 and n % 2 == 0
+    step = resident._FAMILIES[family].lattice_step
+    assert n > 0 and n % step == 0
     assert resident.plan_resident(family, n, n) is not None
-    assert resident.plan_resident(family, n + 2, n + 2) is None
+    assert resident.plan_resident(family, n + step, n + step) is None
     # the plan carries the model numbers it was approved under
     plan = resident.plan_resident(family, n, n)
     assert plan.working_set_bytes <= plan.budget_bytes
     assert plan.plane_bytes == resident.plane_bytes(family, n, n)
+
+
+@pytest.mark.parametrize("family", ["stencil", "multispin", "bitplane"])
+def test_block_plan_heights_are_whole_tiles(family):
+    """Per-half-sweep row blocks are whole dtype tiles (32 int8 rows, 8
+    uint32 rows) that divide the plane, within the VMEM limit, for
+    lattice widths 64 to 32768."""
+    tile = resident._FAMILIES[family].tile_rows
+    m = 64
+    while m <= 32768:
+        plan = resident.block_plan(family, m, m)
+        assert plan.block_rows % tile == 0, (m, plan)
+        assert m % plan.block_rows == 0, (m, plan)
+        assert plan.working_set_bytes <= plan.vmem_limit_bytes
+        assert plan.vmem_limit_bytes == resident.VMEM_LIMIT_BYTES
+        m *= 2
+
+
+def test_block_plan_rows_shrink_as_rows_widen():
+    """Block height follows the row bytes: wider planes, shorter
+    blocks (the pre-planner 256-row default ignored the width)."""
+    rows = [resident.block_plan("stencil", 32768, m).block_rows
+            for m in (1024, 8192, 32768)]
+    assert rows == sorted(rows, reverse=True) and rows[0] > rows[-1]
 
 
 def test_planner_rejects_unknown_family():
@@ -111,9 +139,14 @@ def test_engine_fallback_boundary_bitexact(monkeypatch, engine, family,
     the fitting size routes resident, the spilling size falls back to
     the per-half-sweep kernels -- and BOTH produce the oracle
     trajectory, so the tier decision is unobservable in the physics."""
-    budget = resident.working_set_bytes(family, fit_n, fit_n)
-    assert budget < resident.working_set_bytes(family, spill_n, spill_n)
-    monkeypatch.setattr(resident, "VMEM_BUDGET_BYTES", budget)
+    # scale the modeled bytes per cell so the limit falls between them
+    fit_ws = resident.working_set_bytes(family, fit_n, fit_n)
+    spill_ws = resident.working_set_bytes(family, spill_n, spill_n)
+    assert fit_ws < spill_ws
+    per_cell = resident._FAMILIES[family].resident_bytes
+    scaled = resident.VMEM_LIMIT_BYTES * per_cell // spill_ws + 1
+    monkeypatch.setitem(resident._FAMILIES, family, dataclasses.replace(
+        resident._FAMILIES[family], resident_bytes=scaled))
 
     oracle = {"stencil_pallas": "basic_philox",
               "bitplane_pallas": "bitplane"}[engine]

@@ -267,6 +267,39 @@ def test_ensemble_demotion_bit_exact():
     assert s.state_digest() == ref.state_digest()
 
 
+#: Mosaic's refusal of a kernel whose working set is over the limit
+_MOSAIC_VMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %pallas_call = custom-call(...), "
+    'custom_call_target="tpu_custom_call". Scoped allocation with size '
+    "28.90M and limit 16.00M exceeded scoped vmem limit by 12.90M.")
+
+
+def test_mosaic_vmem_refusal_is_not_a_runtime_oom():
+    exc = RuntimeError(_MOSAIC_VMEM_REFUSAL)
+    assert degrade.is_compile_refusal(exc)
+    assert not degrade.is_resident_oom(exc)
+    assert not degrade.is_transient(exc)
+    assert not degrade.is_compile_refusal(SimulatedResourceExhausted())
+
+
+def test_mosaic_vmem_refusal_does_not_demote():
+    """A compile refusal propagates: the resident tier is not demoted
+    and nothing falls back in silence."""
+    s = Session.open(_spec("multispin_pallas"))
+    assert s.engine.resident_plan is not None
+    before = tel.REGISTRY.counter("resident.demote").value
+
+    def refused():
+        raise RuntimeError(_MOSAIC_VMEM_REFUSAL)
+
+    with pytest.raises(RuntimeError, match="scoped vmem limit"):
+        degrade.run_dispatch(refused, engine=s.engine)
+    assert s.engine.resident_plan is not None
+    assert degrade.demotion_reason("multispin", 16, 32) is None
+    assert tel.REGISTRY.counter("resident.demote").value == before
+
+
 def test_simulated_oom_classifies_like_real():
     exc = SimulatedResourceExhausted()
     assert degrade.is_resident_oom(exc)

@@ -43,3 +43,17 @@ def test_uniforms_offset_advances_stream():
     u1 = rng.uniforms(1, seq, jnp.uint32(0))[0]
     u2 = rng.uniforms(1, seq, jnp.uint32(1))[0]
     assert not bool((u1 == u2).all())
+
+
+def test_u32_to_float_matches_direct_cast_bitwise():
+    """The Mosaic-safe split conversion rounds exactly like the direct
+    uint32 -> float32 cast, on the edge cases and on random words."""
+    edge = np.array([0, 1, 2**24 - 1, 2**24, 2**24 + 1, 2**31 - 1, 2**31,
+                     2**31 + 1, 0xFFFFFF7F, 0xFFFFFF80, 0xFFFFFFFF],
+                    np.uint32)
+    rand = np.random.default_rng(0).integers(0, 2**32, 100_000,
+                                             dtype=np.uint64)
+    words = jnp.asarray(np.concatenate([edge, rand.astype(np.uint32)]))
+    got = np.asarray(rng.u32_to_float(words)).view(np.uint32)
+    want = np.asarray(words.astype(jnp.float32)).view(np.uint32)
+    np.testing.assert_array_equal(got, want)
