@@ -1,0 +1,10 @@
+"""Run from the checkout root: ``PYTHONPATH=src python -m pytest
+chipbench/tests``.  Puts the checkout root on ``sys.path`` so that the
+tests import the harness as ``chipbench``."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
