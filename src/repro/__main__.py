@@ -15,6 +15,9 @@ any result is replayable from one blob:
     # validate + print the dispatch plan, no device work
     python -m repro run spec.json --dry-run
 
+    # device ops and the program's repro.* spans in one profile
+    python -m repro run spec.json --sweeps 100 --profile prof/
+
     # resume a checkpoint (single, ensemble, or sharded -- the spec
     # inside the file picks the runner)
     python -m repro run --restore ckpt.npz --sweeps 500
@@ -120,6 +123,18 @@ def _cmd_supervise(args, spec) -> int:
 
 
 def cmd_run(args) -> int:
+    if not args.profile:
+        return _run(args)
+    import jax
+    # one profile holds the device ops and the repro.* spans
+    with jax.profiler.trace(args.profile):
+        rc = _run(args)
+    print(f"# wrote profile under {args.profile} (open in Perfetto or "
+          "xprof)", file=sys.stderr)
+    return rc
+
+
+def _run(args) -> int:
     from repro.api import Session, describe
 
     if args.trace:
@@ -287,6 +302,10 @@ def main(argv=None) -> int:
                      help="enable span tracing; write the Chrome trace "
                           "(.json, Perfetto-loadable) or .jsonl stream "
                           "+ metrics snapshot here")
+    run.add_argument("--profile", default="", metavar="DIR",
+                     help="run under jax.profiler.trace(DIR): the device "
+                          "ops and the repro.* spans in one profile "
+                          "(Perfetto / xprof)")
     run.set_defaults(fn=cmd_run)
 
     from repro.serve.__main__ import add_serve_args, run_server

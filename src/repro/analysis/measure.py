@@ -103,7 +103,8 @@ def _scan_body(engine, plan: MeasurementPlan):
             st = engine.scan_step(st, inv_temp, seed, step,
                                   plan.sweeps_between)
             step = step + plan.sweeps_between
-            o = engine.observables(st, inv_temp)
+            with jax.named_scope("observables"):
+                o = engine.observables(st, inv_temp)
             missing = set(plan.fields) - set(o)
             if missing:
                 raise ValueError(
@@ -181,7 +182,9 @@ def measure_scan(engine, state, plan: MeasurementPlan, step_count: int = 0):
             dsp.fence(traj)
         _account(engine, plan, batch=1)
         sp.fence((state, traj))
-    traj = {k: np.asarray(v) for k, v in traj.items()}
+    with tel.span("measure.fetch", engine=engine.name,
+                  n_measure=plan.n_measure):
+        traj = {k: np.asarray(v) for k, v in traj.items()}
     return state, traj, step_count + plan.total_sweeps
 
 
@@ -210,5 +213,8 @@ def measure_scan_batched(engine, states, inv_temps, seeds,
         sp.fence((states, traj))
     # (B, n, ...) -> (n, B, ...): moveaxis, not .T, so replicated engines'
     # per-replica observable vectors keep their trailing axis intact
-    traj = {k: np.moveaxis(np.asarray(v), 0, 1) for k, v in traj.items()}
+    with tel.span("measure.fetch", engine=engine.name,
+                  n_measure=plan.n_measure, batch=batch):
+        traj = {k: np.moveaxis(np.asarray(v), 0, 1)
+                for k, v in traj.items()}
     return states, traj, step_count + plan.total_sweeps

@@ -374,19 +374,24 @@ class _ShardedRunner:
 
     def run(self, n_sweeps: int):
         def attempt():
-            fresh = n_sweeps not in self._jit_cache
-            scale = 2 if self._dist_plan is not None \
-                else self._offset_scale
-            step, sh = self._step(n_sweeps)
-            with self.engine._dispatch(
-                    n_sweeps, compile="first" if fresh else "steady",
-                    mesh=list(self.spec.mesh.shape),
-                    **self._dist_attrs) as sp:
-                state = step(*self.state,
-                             jnp.float32(self.cfg.inv_temp),
-                             jnp.uint32(scale * self.step_count))
-                sp.set(halo_exchanges=self._record_halo(n_sweeps))
-                sp.fence(state)
+            # the host side of one sharded call: the step lookup, the
+            # scalar arguments placed for the mesh, the dispatch
+            with tel.span("sharded.call", k=n_sweeps,
+                          mesh=list(self.spec.mesh.shape)):
+                fresh = n_sweeps not in self._jit_cache
+                scale = 2 if self._dist_plan is not None \
+                    else self._offset_scale
+                step, sh = self._step(n_sweeps)
+                with tel.span("sharded.args"):
+                    beta = jnp.float32(self.cfg.inv_temp)
+                    offset = jnp.uint32(scale * self.step_count)
+                with self.engine._dispatch(
+                        n_sweeps, compile="first" if fresh else "steady",
+                        mesh=list(self.spec.mesh.shape),
+                        **self._dist_attrs) as sp:
+                    state = step(*self.state, beta, offset)
+                    sp.set(halo_exchanges=self._record_halo(n_sweeps))
+                    sp.fence(state)
             return state
 
         self.state = degrade.run_dispatch(attempt, engine=self.engine,
@@ -562,18 +567,6 @@ class Session:
         self._runner.step_count = v
 
     # -- execution ----------------------------------------------------------
-    def _flip_rate(self, n_sweeps: int, duration_ns) -> None:
-        """Update the rolling flips/ns gauge from a fenced span close
-        (only possible when tracing is on: otherwise there is no honest
-        device-complete duration to divide by)."""
-        if not duration_ns:
-            return
-        eng = self._runner.engine
-        batch = self._runner.size if self.mode == "ensemble" else 1
-        flips = n_sweeps * eng.cfg.n * eng.cfg.m * eng.replicas * batch
-        tel.REGISTRY.gauge("rolling_flips_per_ns").set(
-            flips / duration_ns)
-
     def run(self, n_sweeps: int):
         """Advance ``n_sweeps`` full lattice sweeps (every member, in
         ensemble mode).  Ensemble mode returns the (B,) per-member
@@ -582,7 +575,6 @@ class Session:
                       engine=self.spec.engine.name, k=n_sweeps) as sp:
             out = self._runner.run(n_sweeps)
             sp.fence(self.state)
-        self._flip_rate(n_sweeps, sp.duration_ns)
         return out
 
     def measure(self, plan=None) -> dict:
@@ -604,7 +596,6 @@ class Session:
                       thermalize=plan.thermalize) as sp:
             traj = self._runner.measure(plan)
             sp.fence(self.state)
-        self._flip_rate(plan.total_sweeps, sp.duration_ns)
         return traj
 
     def trajectory(self, n_measure: int, sweeps_between: int,
@@ -701,10 +692,16 @@ class Session:
     @classmethod
     def _from_arrays(cls, spec: RunSpec, arrays: dict,
                      step_count: int) -> "Session":
-        runner = _RUNNERS[spec.mode](spec, state=_SENTINEL,
-                                     step_count=step_count)
-        runner.load_arrays(arrays)
-        return cls(spec, runner=runner)
+        with tel.span("session.build", mode=spec.mode,
+                      engine=spec.engine.name,
+                      lattice=(spec.lattice.n, spec.lattice.m),
+                      step_count=step_count) as sp:
+            runner = _RUNNERS[spec.mode](spec, state=_SENTINEL,
+                                         step_count=step_count)
+            runner.load_arrays(arrays)
+            session = cls(spec, runner=runner)
+            sp.fence(session.state)
+        return session
 
 
 #: placeholder state handed to runner __init__ so restore skips the

@@ -69,10 +69,11 @@ def ring_shift(x: jax.Array, axis_names: Sequence[str], shift: int):
 
 def _exchange_halos(op, row_axes, col_axes):
     """Return (top, bottom, left, right) halos of the opposite-color plane."""
-    top = ring_shift(op[-1:, :], row_axes, +1)      # last row of upper nbr
-    bottom = ring_shift(op[:1, :], row_axes, -1)    # first row of lower nbr
-    left = ring_shift(op[:, -1:], col_axes, +1)
-    right = ring_shift(op[:, :1], col_axes, -1)
+    with jax.named_scope("halo_exchange"):
+        top = ring_shift(op[-1:, :], row_axes, +1)    # last row of upper nbr
+        bottom = ring_shift(op[:1, :], row_axes, -1)  # first row of lower nbr
+        left = ring_shift(op[:, -1:], col_axes, +1)
+        right = ring_shift(op[:, :1], col_axes, -1)
     return top, bottom, left, right
 
 

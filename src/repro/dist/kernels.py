@@ -33,6 +33,7 @@ from repro.core import rng as crng
 from repro.kernels import resident as vmem
 from repro.kernels.bitplane import resident as bp_res
 from repro.kernels.multispin import resident as ms_res
+from repro.kernels.names import kernel_name
 from repro.kernels.stencil import resident as st_res
 
 _VMEM = pl.BlockSpec(memory_space=pltpu.VMEM)
@@ -85,6 +86,7 @@ def stencil_shard_sweeps(black, white, inv_temp, gidx, *,
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("stencil", "shard_resident"),
     )(beta, seeds, gidx, black, white)
 
 
@@ -117,6 +119,7 @@ def multispin_shard_sweeps(black, white, thresholds, widx, *,
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("multispin", "shard_resident"),
     )(seeds, thresholds, widx, black, white)
 
 
@@ -150,4 +153,5 @@ def bitplane_shard_sweeps(black, white, thresholds, gidx, lane, *,
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("bitplane", "shard_resident"),
     )(seeds, thresholds, gidx, lane, black, white)

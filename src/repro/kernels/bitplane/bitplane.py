@@ -26,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitplane as bpc
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -97,4 +98,5 @@ def bitplane_update(target_words, op_words, inv_temp, *, is_black: bool,
                                        target_words.dtype),
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("bitplane", "stream"),
     )(seeds, thresholds, target_words, op_words, op_words, op_words)

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bitplane as bpc
 from repro.core import rng as crng
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 
 def _half_sweep(target, op, is_black: bool, thr, k0, k1, offset,
@@ -111,4 +112,5 @@ def bitplane_sweeps_resident(black_words, white_words, inv_temp, *,
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("bitplane", "resident"),
     )(seeds, thresholds, black_words, white_words)

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import lattice as lat
 from repro.core import rng as crng
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 DEFAULT_BLOCK_ROWS = 256
 _NIB = lat.NIBBLE_BITS
@@ -124,4 +125,5 @@ def multispin_update(target_words, op_words, inv_temp, *, is_black: bool,
                                        target_words.dtype),
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("multispin", "stream"),
     )(seeds, thresholds, target_words, op_words, op_words, op_words)

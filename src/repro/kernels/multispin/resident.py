@@ -26,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import lattice as lat
 from repro.core import rng as crng
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 _NIB = lat.NIBBLE_BITS
 
@@ -135,4 +136,5 @@ def multispin_sweeps_resident(black_words, white_words, inv_temp, *,
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("multispin", "resident"),
     )(seeds, thresholds, black_words, white_words)

@@ -30,6 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 
 def _half_sweep(target, op, inv_temp, is_black: bool, k0, k1, offset,
@@ -123,4 +124,5 @@ def stencil_sweeps_resident(black, white, inv_temp, *, n_sweeps: int,
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("stencil", "resident"),
     )(beta, seeds, black, white)

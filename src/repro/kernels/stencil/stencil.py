@@ -23,6 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
 from repro.kernels import resident as vmem
+from repro.kernels.names import kernel_name
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -124,4 +125,5 @@ def stencil_update(target, op_plane, inv_temp, *, is_black: bool,
         out_shape=jax.ShapeDtypeStruct(target.shape, target.dtype),
         interpret=interpret,
         compiler_params=vmem.compiler_params(),
+        name=kernel_name("stencil", "stream"),
     )(*args)

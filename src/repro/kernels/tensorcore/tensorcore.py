@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng as crng
 from repro.core.tensorcore import make_kernel_matrix
+from repro.kernels.names import kernel_name
 
 DEFAULT_BLOCK = 128
 
@@ -134,6 +135,7 @@ def tensorcore_update(planes: dict, color: str, inv_temp, *, seed: int = 0,
         out_shape=(jax.ShapeDtypeStruct(t1.shape, t1.dtype),
                    jax.ShapeDtypeStruct(t2.shape, t2.dtype)),
         interpret=interpret,
+        name=kernel_name("tensorcore", "stream"),
     )(beta, seeds, kmat, t1, t2, a, a, a, b, b, b)
 
     out = dict(planes)

@@ -41,8 +41,23 @@ Counter semantics (asserted in tests/test_telemetry.py):
   (tests/test_dist.py).
 * ``halo_bytes``     -- bytes moved across the mesh by those
   exchanges, summed over every shard.
+* ``compile_ns``     -- nanoseconds JAX spent tracing, lowering to MLIR
+  and backend-compiling (or loading from the persistent cache) while a
+  span was open on the compiling thread; nested events count once.
+* ``compile_cache_misses`` -- persistent compilation cache misses
+  (programs compiled and written to the cache) under the same rule.
+
+The compile account counts only inside spans, so work of a caller
+outside the program (a benchmark's own programs, a reference replay)
+is left out.
 """
 from __future__ import annotations
+
+import collections
+import threading
+import time
+
+import jax
 
 from .metrics import (REGISTRY, Counter, Gauge, Histogram,
                       MetricsRegistry, diff_counters)
@@ -56,7 +71,7 @@ __all__ = [
     "TelemetryError", "validate_snapshot", "validate_trace",
     "validate_event", "diff_counters",
     "DISPATCHES", "SWEEPS", "SPIN_FLIPS", "PHILOX_DRAWS",
-    "HALO_EXCHANGES", "HALO_BYTES",
+    "HALO_EXCHANGES", "HALO_BYTES", "COMPILE_NS", "COMPILE_CACHE_MISSES",
     "enable", "disable", "enabled", "reset", "span", "instant",
     "record_dispatch", "record_halo_exchange", "export",
 ]
@@ -68,6 +83,15 @@ SPIN_FLIPS = REGISTRY.counter("spin_flips")
 PHILOX_DRAWS = REGISTRY.counter("philox_draws")
 HALO_EXCHANGES = REGISTRY.counter("halo_exchanges")
 HALO_BYTES = REGISTRY.counter("halo_bytes")
+COMPILE_NS = REGISTRY.counter("compile_ns")
+COMPILE_CACHE_MISSES = REGISTRY.counter("compile_cache_misses")
+
+#: JAX's duration events of one program's trace, lowering and compile
+COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration"))
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 def enable() -> None:
@@ -142,3 +166,44 @@ def export(path: str, meta: dict | None = None) -> str:
         return TRACER.export_jsonl(path, metrics=snap, meta=meta)
     validate_trace(TRACER.to_chrome(metrics=snap, meta=meta))
     return TRACER.export_chrome(path, metrics=snap, meta=meta)
+
+
+class _CompileAccount:
+    """The ``jax.monitoring`` listeners behind :data:`COMPILE_NS` and
+    :data:`COMPILE_CACHE_MISSES`.
+
+    A duration event arrives when it ends.  Events nest on a thread (a
+    jit traced inside another's trace), so each thread keeps the
+    ``(start, duration)`` of the last events it counted; a new event
+    that starts before them contains them, and only its own remainder
+    is added."""
+
+    #: counted events kept per thread (an event nesting more is rare)
+    KEEP = 256
+
+    def __init__(self):
+        self._tls = threading.local()
+
+    def on_duration(self, event: str, duration_secs: float,
+                    **kwargs) -> None:
+        if event not in COMPILE_EVENTS or not TRACER.open_depth():
+            return
+        dur = int(duration_secs * 1e9)
+        start = time.perf_counter_ns() - dur
+        done = getattr(self._tls, "done", None)
+        if done is None:
+            done = self._tls.done = collections.deque(maxlen=self.KEEP)
+        inner = 0
+        while done and done[-1][0] >= start:
+            inner += done.pop()[1]
+        done.append((start, dur))
+        COMPILE_NS.inc(max(dur - inner, 0))
+
+    def on_event(self, event: str, **kwargs) -> None:
+        if event == CACHE_MISS_EVENT and TRACER.open_depth():
+            COMPILE_CACHE_MISSES.inc()
+
+
+_COMPILES = _CompileAccount()
+jax.monitoring.register_event_duration_secs_listener(_COMPILES.on_duration)
+jax.monitoring.register_event_listener(_COMPILES.on_event)

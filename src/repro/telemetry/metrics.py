@@ -11,7 +11,7 @@ Three instrument kinds, all process-global through :data:`REGISTRY`:
 
 * :class:`Counter`   -- monotone int (dispatches, sweeps, spin_flips,
   philox_draws, planner decisions).  ``value`` reads, ``inc`` adds.
-* :class:`Gauge`     -- last-written float (rolling flips/ns).
+* :class:`Gauge`     -- last-written float.
 * :class:`Histogram` -- streaming count/sum/min/max of float samples;
   span close times feed ``span_ms.<name>`` histograms when tracing is
   enabled, so the snapshot carries a per-phase timing summary even

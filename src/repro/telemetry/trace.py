@@ -7,9 +7,18 @@ A *span* is one named, attributed, nested interval of host wall-clock
 asynchronous, a span that times device work must *fence* before it
 closes: ``sp.fence(out)`` remembers the output pytree and the tracer
 ``jax.block_until_ready``-s it on exit, so the recorded duration covers
-the device work, not just the enqueue.  Fencing (like every other part
-of a span) is a NO-OP while tracing is disabled -- the default -- so
-instrumented code keeps JAX's async pipelining when nobody is looking.
+the device work, not just the enqueue.  Fencing (like the event list)
+is a NO-OP while tracing is disabled -- the default -- so instrumented
+code keeps JAX's async pipelining when nobody is looking.
+
+Every span, enabled or not, also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` while a
+profiler session is active (without one it is skipped after one check).
+That puts the program's phases on the profile's host plane, on the
+same clock as the device ops (``python -m repro run --profile DIR``).
+The tracer also keeps a thread-local count of the spans open
+(:meth:`Tracer.open_depth`), which the compile account of
+:mod:`repro.telemetry` reads.
 
 Export formats:
 
@@ -31,6 +40,8 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import REGISTRY
 
@@ -87,15 +98,24 @@ class SpanHandle:
 
 
 class _Scope:
-    """Context manager returned by :meth:`Tracer.span`."""
+    """Context manager returned by :meth:`Tracer.span`: the open-span
+    depth always, the profiler annotation while a profiler session is
+    active, the recorded span only while tracing is enabled."""
 
-    __slots__ = ("_tracer", "_handle")
+    __slots__ = ("_tracer", "_handle", "_annotation")
 
-    def __init__(self, tracer: "Tracer", handle):
+    def __init__(self, tracer: "Tracer", name: str, handle):
         self._tracer = tracer
         self._handle = handle
+        # a profiler session records the annotation; without one it
+        # would be a no-op, so it is not built
+        self._annotation = TraceAnnotation("repro." + name) \
+            if TraceAnnotation.is_enabled() else None
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._tracer._tls.depth += 1
         h = self._handle
         if h is not NULL_SPAN:
             self._tracer._push(h)
@@ -103,10 +123,23 @@ class _Scope:
         return h
 
     def __exit__(self, exc_type, exc, tb):
-        h = self._handle
-        if h is not NULL_SPAN:
-            self._tracer._close(h, error=exc_type is not None)
+        try:
+            h = self._handle
+            if h is not NULL_SPAN:
+                self._tracer._close(h, error=exc_type is not None)
+        finally:
+            self._tracer._tls.depth -= 1
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
         return False
+
+
+class _Local(threading.local):
+    """Per-thread state: the open-span depth (every span) and the stack
+    of recorded spans (enabled tracing only)."""
+
+    depth = 0
+    stack = None
 
 
 class Tracer:
@@ -116,7 +149,7 @@ class Tracer:
         self.enabled = False
         self._lock = threading.Lock()
         self._events: List[dict] = []
-        self._tls = threading.local()
+        self._tls = _Local()
         self._origin_ns = time.perf_counter_ns()
 
     # -- lifecycle ----------------------------------------------------------
@@ -133,10 +166,14 @@ class Tracer:
 
     # -- recording ----------------------------------------------------------
     def _stack(self) -> list:
-        st = getattr(self._tls, "stack", None)
+        st = self._tls.stack
         if st is None:
             st = self._tls.stack = []
         return st
+
+    def open_depth(self) -> int:
+        """Spans open on the calling thread, enabled or not."""
+        return self._tls.depth
 
     def _push(self, handle: SpanHandle) -> None:
         st = self._stack()
@@ -146,16 +183,18 @@ class Tracer:
     def span(self, name: str, **attrs) -> _Scope:
         """``with tracer.span("dispatch", engine="multispin") as sp:``
 
-        Yields :data:`NULL_SPAN` while disabled.  Attributes are
+        Yields :data:`NULL_SPAN` while disabled, and never fences
+        then; the ``repro.<name>`` profiler annotation is opened either
+        way while a profiler session is active.  Attributes are
         JSON-normalized at entry; ``sp.set(...)`` adds more, and
         ``sp.fence(out)`` makes the close wait for device completion.
         """
         if not self.enabled:
-            return _Scope(self, NULL_SPAN)
+            return _Scope(self, name, NULL_SPAN)
         handle = SpanHandle(name,
                             {k: _jsonable(v) for k, v in attrs.items()},
                             0, 0, threading.get_ident())
-        return _Scope(self, handle)
+        return _Scope(self, name, handle)
 
     def _close(self, handle: SpanHandle, error: bool = False) -> None:
         if handle._fence is not None:

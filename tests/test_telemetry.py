@@ -6,16 +6,21 @@ export formats, the schema validators (golden file + violation catalogue
 + property round-trips), the summarize/validate CLI, and the
 ``DISPATCH_COUNT`` deprecation shim.
 """
+import glob
 import io
 import json
 import os
 import subprocess
 import sys
+import threading
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 import repro.telemetry as tel
 from _hypothesis_compat import given, settings, st
+from repro.analysis.measure import MeasurementPlan
 from repro.api import EngineSpec, LatticeSpec, RunSpec, Session, SweepSpec
 from repro.api import describe
 from repro.kernels.resident import decision_attrs
@@ -400,8 +405,6 @@ def test_session_run_counters_and_spans(engine, params, traced):
     assert run["ts_us"] <= dsp["ts_us"]
     assert dsp["ts_us"] + dsp["dur_us"] \
         <= run["ts_us"] + run["dur_us"] + 1e-3
-    # traced runs feed the rolling throughput gauge
-    assert tel.REGISTRY.gauge("rolling_flips_per_ns").value is not None
 
 
 def test_session_measure_counts_one_fused_dispatch(traced):
@@ -486,6 +489,11 @@ def test_cli_traced_run_acceptance(tmp_path):
     recovery = {k: v for k, v in counters.items()
                 if k.startswith(("resilience.", "resident.", "ckpt."))}
     assert all(v == 0 for v in recovery.values()), recovery
+    # the compile account: the run's one program was traced, lowered
+    # and compiled (or loaded) inside its spans
+    compiles = {k: counters.pop(k)
+                for k in ("compile_ns", "compile_cache_misses")}
+    assert compiles["compile_ns"] > 0, compiles
     assert {k: v for k, v in counters.items()
             if k not in recovery} == {
         "dispatches": 1, "sweeps": 8,
@@ -496,6 +504,163 @@ def test_cli_traced_run_acceptance(tmp_path):
         [sys.executable, "-m", "repro.telemetry", "summarize", trace],
         check=True, env=env, timeout=120, capture_output=True, text=True)
     assert "dispatches" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock and the compile account
+# ---------------------------------------------------------------------------
+
+SPEC16 = RunSpec(lattice=LatticeSpec(n=16, m=16),
+                 engine=EngineSpec(name="multispin"), temperature=2.2,
+                 seed=5)
+
+
+def _host_events(profile_dir) -> set:
+    """Names of the events on the host planes of a written profile."""
+    (path,) = glob.glob(os.path.join(str(profile_dir), "**",
+                                     "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    assert not tel.enabled()
+    session = Session.open(SPEC16)
+    session.run(2)  # compiled before the profile starts
+    with jax.profiler.trace(str(tmp_path)):
+        session.run(2)
+        jax.block_until_ready(session.state)
+    names = _host_events(tmp_path)
+    assert {"repro.session.run", "repro.dispatch"} <= names, names
+
+
+def test_cli_profile_holds_program_spans(tmp_path):
+    """``python -m repro run --profile DIR``: one profile with the
+    run's repro.* spans on its host plane."""
+    prof = tmp_path / "prof"
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    subprocess.run(
+        [sys.executable, "-m", "repro", "run", "--n", "16",
+         "--engine", "multispin", "--sweeps", "2", "--profile", str(prof)],
+        check=True, env=env, timeout=600, cwd=str(tmp_path))
+    names = _host_events(prof)
+    assert {"repro.session.open", "repro.session.run",
+            "repro.dispatch"} <= names, names
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_disabled_spans_neither_fence_nor_record(on, monkeypatch):
+    """Off, a span fences nothing and records no event.  On, the same
+    calls fence and record (so the check can see both)."""
+    fences = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        fences.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    tel.TRACER.clear()
+    if on:
+        tel.enable()
+    try:
+        session = Session.open(SPEC16)
+        session.run(2)
+        session.measure(MeasurementPlan(n_measure=2, sweeps_between=1))
+    finally:
+        tel.disable()
+    names = tel.TRACER.span_names()
+    tel.TRACER.clear()
+    assert tel.TRACER.open_depth() == 0
+    if on:
+        assert fences and {"session.run", "measure_scan",
+                           "measure.fetch"} <= set(names)
+    else:
+        assert fences == [] and names == []
+
+
+def test_open_depth_counts_every_span_per_thread():
+    assert not tel.enabled() and tel.TRACER.open_depth() == 0
+    seen = []
+    with tel.span("a"):
+        with tel.span("b"):
+            seen.append(tel.TRACER.open_depth())
+            t = threading.Thread(
+                target=lambda: seen.append(tel.TRACER.open_depth()))
+            t.start()
+            t.join(timeout=30)
+        with pytest.raises(RuntimeError):
+            with tel.span("c"):
+                raise RuntimeError("x")
+        seen.append(tel.TRACER.open_depth())
+    assert not t.is_alive()
+    assert seen == [2, 0, 1] and tel.TRACER.open_depth() == 0
+
+
+def _fresh_program(c):
+    """A function JAX has not traced before: each call makes its own."""
+    return jax.jit(lambda x: x * c + 1)
+
+
+def test_compile_account_counts_inside_spans_only():
+    x = jnp.arange(7.0)
+    ns = tel.COMPILE_NS.value
+    _fresh_program(3.0)(x).block_until_ready()
+    assert tel.COMPILE_NS.value == ns
+    with tel.span("test.compile"):
+        _fresh_program(3.0)(x).block_until_ready()
+    assert tel.COMPILE_NS.value > ns
+    ns = tel.COMPILE_NS.value
+    with tel.span("test.compile"):
+        f = _fresh_program(5.0)
+        f(x).block_until_ready()
+        grown = tel.COMPILE_NS.value - ns
+        f(x).block_until_ready()  # cached: no more compile time
+    assert tel.COMPILE_NS.value - ns == grown > 0
+
+
+def test_compile_account_nested_trace_counts_once():
+    """An event that contains earlier ones (a jit traced inside
+    another's trace) adds only its own remainder."""
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    account = tel._CompileAccount()  # none of this thread's real events
+    ns = tel.COMPILE_NS.value
+    with tel.span("test.compile"):
+        account.on_duration(event, 1.0)   # inner, ends first
+        account.on_duration(event, 3.0)   # outer, contains it
+        account.on_duration(event, 1e-4)  # later, disjoint
+        account.on_duration("/jax/other_duration", 5.0)
+    assert tel.COMPILE_NS.value - ns == 3_000_000_000 + 100_000
+    account.on_duration(event, 2.0)  # outside every span
+    assert tel.COMPILE_NS.value - ns == 3_000_000_000 + 100_000
+
+
+def test_compile_account_counts_cache_misses(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_enable_compilation_cache")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    x = jnp.arange(9.0)
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        misses = tel.COMPILE_CACHE_MISSES.value
+        _fresh_program(7.0)(x).block_until_ready()
+        assert tel.COMPILE_CACHE_MISSES.value == misses
+        with tel.span("test.compile"):
+            _fresh_program(11.0)(x).block_until_ready()
+        assert tel.COMPILE_CACHE_MISSES.value == misses + 1
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -18,12 +18,14 @@ a described chip cannot be read back from it.
 """
 import concurrent.futures
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.kernels import resident
+from repro.kernels.names import kernel_name
 
 BLOCKED = ("stencil", "multispin", "bitplane")
 CHIP_COLUMNS = 32768
@@ -130,6 +132,24 @@ def test_kernel_compiles_for_v5e(compiled, case):
     if isinstance(out, Exception):
         raise out
     assert "tpu_custom_call" in out
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_compiled_kernel_op_carries_its_name(compiled, case):
+    """The compiled op is named from ``kernels.names``, which is the
+    name a TPU trace gives the kernel's events."""
+    out = compiled[case]
+    if isinstance(out, Exception):
+        raise out
+    kind, family = case.split("-")
+    tier = {"blocked": "stream", "resident": "resident",
+            "dist": "shard_resident"}[kind]
+    name = kernel_name(family, tier)
+    ops = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", ln).group(1)
+           for ln in out.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in ln]
+    assert ops and all(re.fullmatch(rf"{name}(\.\d+)?", op)
+                       for op in ops), ops
 
 
 def test_planner_sizes_are_chip_sized():
