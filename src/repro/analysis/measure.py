@@ -153,6 +153,7 @@ def _span(engine, plan: MeasurementPlan, fresh: bool, batch: int):
                     sweeps_between=plan.sweeps_between,
                     thermalize=plan.thermalize, batch=batch,
                     replicas=engine.replicas,
+                    observables=engine.observables_path,
                     compile="first" if fresh else "steady")
 
 

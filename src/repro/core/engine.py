@@ -30,7 +30,9 @@ Two hooks added for the measurement subsystem (DESIGN.md S7):
   engine-native state to ``{"m": mean spin, "e": energy/spin}``; the
   default routes through ``full_lattice``, so it is correct for every
   layout (packed words, tensor-core planes, ...) -- engines with a
-  cheaper or physically different path (spin glass) override it;
+  cheaper or physically different path override it: the multispin
+  engines count exactly on the packed words, the spin glass weights
+  its couplings;
 * ``scan_step(state, inv_temp, seed, step_count, n_sweeps)`` -- pure
   version of ``sweeps`` with a *traceable* cumulative-sweep counter, the
   unit that ``repro.analysis.measure.measure_scan`` chains inside one
@@ -97,6 +99,10 @@ class Engine:
     #: this engine's random stream on a device mesh (``None`` = no
     #: sharded execution); the capability flag behind ``MeshSpec``
     dist_factory: ClassVar[Optional[str]] = None
+    #: how :meth:`observables` reads the state, for the ``measure_scan``
+    #: span: ``"full"`` through ``full_lattice``, ``"packed"`` from the
+    #: packed words
+    observables_path: ClassVar[str] = "full"
 
     @classmethod
     def validate_lattice(cls, n: int, m: int) -> None:
@@ -446,8 +452,15 @@ class MultispinEngine(CounterEngine):
     def full_lattice(self, state):
         return lat.merge_checkerboard(*ms.unpack_lattice(*state))
 
+    # exact counts on the packed words: no lattice-sized int8 or float32
+    # copy, bit for bit the full-lattice observables where those are exact
+    observables_path = "packed"
+
     def magnetization(self, state):
-        return obs.magnetization(*ms.unpack_lattice(*state))
+        return ms.packed_magnetization(*state)
+
+    def observables(self, state, inv_temp):
+        return ms.packed_observables(*state)
 
     def sweep_context(self, inv_temp):
         return ms.acceptance_thresholds(inv_temp)

@@ -137,3 +137,133 @@ def pack_lattice(black_pm1, white_pm1):
 def unpack_lattice(black_words, white_words, dtype=jnp.int8):
     return (lat.from_binary(lat.unpack_nibbles(black_words), dtype),
             lat.from_binary(lat.unpack_nibbles(white_words), dtype))
+
+
+# ---------------------------------------------------------------------------
+# observables straight from the packed words
+# ---------------------------------------------------------------------------
+
+_SPIN_MASK = 0x11111111   # bit 0 of every nibble: a word's eight spins
+_LIMB_BITS = 12
+_TOP = 32 - _NIB          # shift that brings a word's last nibble to bit 0
+
+
+def _spin_count(words, mask: int = _SPIN_MASK):
+    """Per-word count of set spins under ``mask`` (int32)."""
+    return jax.lax.population_count(
+        words & jnp.uint32(mask)).astype(jnp.int32)
+
+
+def _exact_sum_f32(terms, bound: int):
+    """float32 of the exact sum of int32 ``terms`` (last axis), rounded once.
+
+    ``|terms| <= bound``.  Each term is split into a low limb of
+    ``_LIMB_BITS`` bits and the rest, summed apart so that neither int32
+    partial sum wraps; the low sum's carry then moves up, leaving
+    total = hi * 2^12 + lo with 0 <= lo < 2^12.  float32(hi) is exact
+    while |total| <= 2^36, so the one float add is the only rounding.
+    """
+    rows = terms.shape[-1]
+    if rows << _LIMB_BITS >= 1 << 31 \
+            or rows * ((bound >> _LIMB_BITS) + 2) >= 1 << 31:
+        raise ValueError(f"{rows} terms of up to {bound} overflow the "
+                         f"int32 limb sums")
+    low = (1 << _LIMB_BITS) - 1
+    lo = jnp.sum(terms & low, axis=-1)
+    hi = jnp.sum(terms >> _LIMB_BITS, axis=-1) + (lo >> _LIMB_BITS)
+    return (hi.astype(jnp.float32) * jnp.float32(1 << _LIMB_BITS)
+            + (lo & low).astype(jnp.float32))
+
+
+def spin_sum_from_counts(up_rows, width: int):
+    """M = sum of the +-1 spins, float32 of the exact integer, from
+    ``up_rows[i]`` in [0, width], the up spins of lattice row i.
+
+    M = 2P - N is summed as per-row terms 2 p_i - width, centred on
+    zero, so no partial sum wraps however large N is.
+    """
+    return _exact_sum_f32(2 * up_rows - width, width)
+
+
+def bond_sum_from_counts(anti_rows, width: int):
+    """B = sum over bonds of sigma_i sigma_j, float32 of the exact
+    integer, from ``anti_rows[i]`` in [0, 2 width], the anti-aligned
+    bonds among row i's ``width`` horizontal bonds and the ``width``
+    vertical ones between rows i - 1 and i.
+
+    With U anti-aligned bonds of 2N, B = 2N - 2U = -2 sum_i (u_i - width):
+    per-row terms centred on zero again, and the doubling is exact.
+    """
+    return -2 * _exact_sum_f32(anti_rows - width, width)
+
+
+def _spin_sum(black_words, white_words):
+    up = _spin_count(black_words) + _spin_count(white_words)
+    width = 2 * black_words.shape[1] * lat.SPINS_PER_WORD
+    return spin_sum_from_counts(jnp.sum(up, axis=-1), width)
+
+
+def _bond_sum(black_words, white_words):
+    """B, each bond counted once, periodic as the update is.
+
+    The neighbours are those of :func:`lattice.packed_neighbor_sums`,
+    read through slices and in-word nibble shifts rather than rolled
+    copies, so XLA reads the planes in place and writes no shifted copy.
+    """
+    b, w = black_words, white_words
+    # vertical: both colours of row r against the other colour of row
+    # r - 1 at the same compact column; row 0 wraps to the last row
+    vert = jnp.sum(_spin_count(b[1:] ^ w[:-1])
+                   + _spin_count(w[1:] ^ b[:-1]), axis=-1)
+    vert0 = jnp.sum(_spin_count(b[:1] ^ w[-1:])
+                    + _spin_count(w[:1] ^ b[-1:]), axis=-1)
+    # horizontal: white at the same compact column, then the side
+    # neighbour (lattice.side_shift): column k + 1 on odd rows, the next
+    # nibble, k - 1 on even rows, the previous one; the nibble that
+    # crosses into the adjacent word is counted from the word edges
+    odd = (jnp.arange(b.shape[0]) % 2 == 1)[:, None]
+    side = jnp.where(odd, _spin_count(b ^ (w >> _NIB), 0x01111111),
+                     _spin_count(b ^ (w << _NIB), 0x11111110))
+    inner = jnp.sum(_spin_count(b ^ w) + side, axis=-1)
+    edge = jnp.sum(jnp.where(odd, (b[:, :-1] >> _TOP) ^ w[:, 1:],
+                             b[:, 1:] ^ (w[:, :-1] >> _TOP)) & 1, axis=-1)
+    edge0 = jnp.where(odd[:, 0], (b[:, -1] >> _TOP) ^ w[:, 0],
+                      b[:, 0] ^ (w[:, -1] >> _TOP)) & 1
+    anti = (jnp.concatenate([vert0, vert]) + inner
+            + (edge + edge0).astype(jnp.int32))
+    width = 2 * b.shape[1] * lat.SPINS_PER_WORD
+    return bond_sum_from_counts(anti, width)
+
+
+@jax.jit
+def packed_sums(black_words, white_words):
+    """(M, B) of packed (N, W) planes, with no lattice-sized int8 or
+    float32 array; jitted so that an eager call on a large or sharded
+    state runs as one program."""
+    return (_spin_sum(black_words, white_words),
+            _bond_sum(black_words, white_words))
+
+
+_packed_spin_sum = jax.jit(_spin_sum)
+
+
+def packed_observables(black_words, white_words) -> dict:
+    """{"m": mean spin, "e": energy per spin} of packed (N, W) planes.
+
+    m = M / N and e = -B / N from the exact sums of :func:`packed_sums`,
+    so both equal the full-lattice observables
+    (``observables.magnetization_full`` / ``energy_per_spin_full``) bit
+    for bit wherever those are exact, up to 2^24 spins.  The division
+    stays outside the jit, so it compiles as the full-lattice path's
+    does in the same context.  Pure and vmap-safe.
+    """
+    spins, bonds = packed_sums(black_words, white_words)
+    # a Python float: an int past 2^31 does not convert to a JAX scalar
+    n_spins = float(2 * black_words.size * lat.SPINS_PER_WORD)
+    return {"m": spins / n_spins, "e": -bonds / n_spins}
+
+
+def packed_magnetization(black_words, white_words):
+    """The "m" of :func:`packed_observables`, without the bond count."""
+    n_spins = float(2 * black_words.size * lat.SPINS_PER_WORD)
+    return _packed_spin_sum(black_words, white_words) / n_spins

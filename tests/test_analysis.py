@@ -1,4 +1,12 @@
 """Measurement & analysis subsystem: fused scan contract + estimators."""
+import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,8 +15,9 @@ from repro.analysis import (MeasurementPlan, RunRecorder, Welford, binder,
                             binder_crossing, blocking_error, jackknife,
                             parse_derived, specific_heat, susceptibility,
                             tau_int)
+from repro.core import multispin as ms
 from repro.core import observables as obs
-from repro.core.engine import ENGINES
+from repro.core.engine import ENGINES, Engine
 from repro.core.ensemble import Ensemble
 from repro.core.sim import SimConfig, Simulation
 
@@ -127,6 +136,254 @@ def test_sim_energy_routes_through_hook(engine):
     if engine != "spinglass":
         full = sim.full_lattice()
         assert hook.reshape(-1)[0] == float(obs.energy_per_spin_full(full))
+
+
+# ---------------------------------------------------------------------------
+# multispin engines: m and e counted on the packed words
+# ---------------------------------------------------------------------------
+
+PACKED_SHAPES = [(8, 16), (16, 32), (64, 128), (24, 80), (2, 16)]
+PATTERNS = ["random", "all_up", "all_down", "antiferro", "row_stripes",
+            "column_stripes"]
+
+
+def _pattern(kind, n, m, seed=0):
+    rows, cols = np.indices((n, m))
+    spins = {
+        "random": lambda: np.random.default_rng(seed).integers(0, 2, (n, m)),
+        "all_up": lambda: np.ones((n, m), int),
+        "all_down": lambda: np.zeros((n, m), int),
+        "antiferro": lambda: (rows + cols) % 2,
+        "row_stripes": lambda: rows % 2,
+        "column_stripes": lambda: cols % 2,
+    }[kind]()
+    return jnp.asarray(2 * spins - 1, jnp.int8)
+
+
+def _full_path(full):
+    return {"m": obs.magnetization_full(full),
+            "e": obs.energy_per_spin_full(full)}
+
+
+def _packed_engine(name, n, m):
+    return ENGINES[name](SimConfig(n=n, m=m, temperature=2.0, engine=name))
+
+
+@pytest.mark.parametrize("shape", PACKED_SHAPES, ids=lambda s: "x".join(
+    map(str, s)))
+@pytest.mark.parametrize("kind", PATTERNS)
+def test_packed_observables_bitwise_equal_full_lattice(shape, kind):
+    """Exact counts, rounded once: the packed path equals the
+    full-lattice observables bit for bit, eagerly and under jit."""
+    full = _pattern(kind, *shape)
+    engine = _packed_engine("multispin", *shape)
+    state = engine.from_full(full)
+    beta = jnp.float32(0.5)
+    for got, want in [(engine.observables(state, beta), _full_path(full)),
+                      (jax.jit(engine.observables)(state, beta),
+                       jax.jit(_full_path)(full))]:
+        assert np.asarray(got["m"]) == np.asarray(want["m"])
+        assert np.asarray(got["e"]) == np.asarray(want["e"])
+    assert np.asarray(engine.magnetization(state)) \
+        == np.asarray(obs.magnetization_full(full))
+    assert np.asarray(engine.energy(state)) \
+        == np.asarray(obs.energy_per_spin_full(full))
+    expect = {"all_up": (1.0, -2.0), "all_down": (-1.0, -2.0),
+              "antiferro": (0.0, 2.0), "row_stripes": (0.0, 0.0),
+              "column_stripes": (0.0, 0.0)}.get(kind)
+    if expect is not None and min(shape) > 2:
+        got = engine.observables(state, beta)
+        assert (float(got["m"]), float(got["e"])) == expect
+
+
+@pytest.mark.parametrize("shape", [(16, 32), (24, 80)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("view", ["observables", "magnetization"])
+def test_packed_observables_under_vmap(shape, view):
+    """The ensemble path maps the hook over a batch of states."""
+    fulls = [_pattern("random", *shape, seed=s) for s in range(4)] + [
+        _pattern(k, *shape) for k in ("all_up", "antiferro")]
+    engine = _packed_engine("multispin_pallas", *shape)
+    states = jax.tree.map(lambda *xs: jnp.stack(xs),
+                          *[engine.from_full(f) for f in fulls])
+    if view == "observables":
+        got = jax.vmap(engine.observables, in_axes=(0, None))(
+            states, jnp.float32(0.5))
+        for i, full in enumerate(fulls):
+            want = _full_path(full)
+            assert np.asarray(got["m"][i]) == np.asarray(want["m"])
+            assert np.asarray(got["e"][i]) == np.asarray(want["e"])
+    else:
+        got = jax.vmap(engine.magnetization)(states)
+        want = [np.asarray(obs.magnetization_full(f)) for f in fulls]
+        np.testing.assert_array_equal(np.asarray(got), np.stack(want))
+
+
+@pytest.mark.parametrize("width", [32768, 65536])
+@pytest.mark.parametrize("up,anti", [("none", "none"), ("all", "all"),
+                                     ("all", "none"), ("none", "all")])
+def test_packed_count_combination_at_extremes(width, up, anti):
+    """P in {0, N} and U in {0, 2N} on chip-sized lattices (N = 2^30
+    and 2^32): no int32 wrap, and m, e land exactly on +-1, +-2."""
+    n = width
+    p_row = {"none": 0, "all": width}[up]
+    u_row = {"none": 0, "all": 2 * width}[anti]
+    spins = ms.spin_sum_from_counts(jnp.full((n,), p_row, jnp.int32),
+                                    width)
+    bonds = ms.bond_sum_from_counts(jnp.full((n,), u_row, jnp.int32),
+                                    width)
+    n_spins = n * width
+    assert float(spins) == 2 * p_row * n - n_spins
+    assert float(bonds) == 2 * n_spins - 2 * u_row * n
+    assert float(spins / float(n_spins)) == (1.0 if up == "all" else -1.0)
+    assert float(-bonds / float(n_spins)) == (2.0 if anti == "all"
+                                              else -2.0)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_packed_count_combination_rounds_once(seed):
+    """Random per-row counts of a 65536^2 lattice: each sum is float32
+    of the exact integer total, rounded once."""
+    width = n = 65536
+    rng = np.random.default_rng(seed)
+    up = rng.integers(0, width + 1, n)
+    anti = rng.integers(0, 2 * width + 1, n)
+    want_m = np.float32(int((2 * up - width).sum()))
+    want_b = np.float32(-2 * int((anti - width).sum()))
+    assert float(ms.spin_sum_from_counts(jnp.asarray(up, jnp.int32),
+                                         width)) == want_m
+    assert float(ms.bond_sum_from_counts(jnp.asarray(anti, jnp.int32),
+                                         width)) == want_b
+
+
+@pytest.mark.parametrize("rows,width", [(1 << 19, 16), (1 << 16, 1 << 29)])
+def test_packed_count_combination_refuses_what_would_wrap(rows, width):
+    with pytest.raises(ValueError, match="int32 limb sums"):
+        ms.spin_sum_from_counts(jnp.zeros((rows,), jnp.int32), width)
+
+
+_BIG = 1 << 26   # elements: a quarter of a 32768^2 lattice
+
+
+def _large_f32_s8(text):
+    """Shapes of f32 / 8-bit arrays of at least _BIG elements in
+    StableHLO text."""
+    found = []
+    for dims, dtype in re.findall(r"tensor<((?:\d+x)+)(f32|i8|ui8)>",
+                                  text):
+        size = int(np.prod([int(d) for d in dims.rstrip("x").split("x")]))
+        if size >= _BIG:
+            found.append(f"{dims}{dtype}")
+    return found
+
+
+def _lowered(view, engine, n, hook=None):
+    planes = tuple(jax.ShapeDtypeStruct((n, n // 16), jnp.uint32)
+                   for _ in range(2))
+    if view == "observables":
+        fn = hook or engine.observables
+        return jax.jit(fn).lower(planes,
+                                 jax.ShapeDtypeStruct((), jnp.float32))
+    return jax.jit(hook or engine.magnetization).lower(planes)
+
+
+@pytest.mark.parametrize("view", ["observables", "magnetization"])
+@pytest.mark.parametrize("n", [32768, 65536])
+def test_packed_observables_build_no_lattice_sized_temporaries(n, view):
+    """Lowered (not run) at chip sizes: the packed hook holds no float32
+    or int8 array of 2^26 elements or more; at 65536^2, N = 2^32 fits no
+    int32 anywhere on the way."""
+    engine = _packed_engine("multispin_pallas", n, n)
+    assert _large_f32_s8(_lowered(view, engine, n).as_text()) == []
+
+
+@pytest.mark.parametrize("view", ["observables", "magnetization"])
+def test_full_lattice_default_builds_lattice_sized_temporaries(view):
+    """The control for the test above: the full-lattice default the
+    multispin engines no longer take holds such arrays at 32768^2."""
+    n = 32768
+    engine = _packed_engine("multispin_pallas", n, n)
+    default = {"observables": lambda s, b: Engine.observables(engine, s, b),
+               "magnetization": lambda s: Engine.magnetization(engine, s)}
+    assert _large_f32_s8(_lowered(view, engine, n, default[view]).as_text())
+
+
+@pytest.mark.parametrize("engine,path", [("multispin", "packed"),
+                                         ("multispin_pallas", "packed"),
+                                         ("basic_philox", "full")])
+def test_measure_scan_span_names_observables_path(engine, path):
+    import repro.telemetry as tel
+    tel.TRACER.clear()
+    tel.enable()
+    try:
+        Simulation(SimConfig(n=16, m=16, temperature=2.0, seed=3,
+                             engine=engine)).trajectory(2, 1)
+        scans = [e for e in tel.TRACER.events if e["name"] == "measure_scan"]
+    finally:
+        tel.disable()
+        tel.TRACER.clear()
+    assert scans and scans[-1]["args"]["observables"] == path
+
+
+_MESH_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import numpy as np
+    from repro.api import (EngineSpec, LatticeSpec, MeshSpec, RunSpec,
+                           Session, SweepSpec)
+    from repro.core import observables as obs
+
+    out = {}
+    for engine in ("multispin", "multispin_pallas"):
+        def spec(mesh):
+            return RunSpec(lattice=LatticeSpec(n=64, m=256),
+                           engine=EngineSpec(engine), temperature=2.27,
+                           seed=2147483659, mesh=mesh,
+                           sweep=SweepSpec(thermalize=2, measure_every=1,
+                                           n_measure=4))
+        single = Session.open(spec(None))
+        want = single.measure()
+        sharded = Session.open(spec(MeshSpec((2, 2))))
+        got = sharded.measure()
+        full = sharded.full_lattice()
+        out[engine] = {
+            "devices": len(sharded._runner.state[0].sharding.device_set),
+            "samples_equal": all(
+                np.array_equal(got[k], want[k]) for k in ("m", "e")),
+            "m_equal": sharded.magnetization()
+                       == float(obs.magnetization_full(full)),
+            "e_equal": sharded.energy()
+                       == float(obs.energy_per_spin_full(full)),
+        }
+    print(json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def mesh_measure():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
+                                     "src")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("engine", ["multispin", "multispin_pallas"])
+def test_packed_observables_on_a_four_device_mesh(mesh_measure, engine):
+    """Session.measure on a sharded 2x2 state calls the packed hook
+    eagerly: the samples equal the single-device run's bit for bit, and
+    the final m and e the full-lattice path's.  N is a power of two:
+    otherwise the fused scan's division by the constant N compiles to a
+    multiply by its reciprocal, which can differ from the eager division
+    by one ulp on either path."""
+    r = mesh_measure[engine]
+    assert r["devices"] == 4
+    assert r["samples_equal"]
+    assert r["m_equal"] and r["e_equal"]
 
 
 # ---------------------------------------------------------------------------
