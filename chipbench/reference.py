@@ -11,20 +11,14 @@ column 2k + i % 2, white[i, k] the one at column 2k + (i + 1) % 2.  A
 sweep updates black, then white; half-sweep ``c`` of sweep ``s`` (both
 counted from the start of the run) has the Philox offset 2s + c.
 
-Two random streams, one per engine family:
-
-* ``site`` (basic stencil): the cell at (i, k) of the target plane draws
-  ``philox4x32_10(counter=(offset, 0, i * m/2 + k, 0), key)[0]``, turns it
-  into u = float32(bits) * 2^-32 and flips when
-  ``u < exp(-2 beta * nn * s)`` in float32, nn being the sum of its four
-  neighbours;
-* ``word`` (multi-spin coding, 8 cells to a word): the cells 8w..8w+7 of
-  row i share word w' = i * m/16 + w; two Philox calls with counters
-  (2 offset, 0, w', 0) and (2 offset + 1, 0, w', 0) give eight words, of
-  which cell 8w + j takes number j.  A cell flips when that word is below
-  a threshold: in 0/1 spins s and neighbour count c,
-  p = exp(-2 beta (2s - 1)(2c - 4)) in float32, and the threshold is
-  uint32(p * 2^32) for p < 1, else 2^32 - 1.
+This module holds what every random stream shares: Philox4x32-10, the
+neighbour geometry, row blocking, the sweep and offset loop and the
+exact observables.  A stream (``chipbench/streams/<stream>.py``) says
+which Philox numbers a cell draws and how it accepts a flip, in its
+``flips(t, nn, rows, beta, k0, k1, offset, precision)``.  A replay
+advances several lattices at once (a state can hold many, as bitplane
+words hold 32), and the stream draws each row block once for all of
+them.
 
 The key is (seed mod 2^32, seed >> 32).  ``precision="bfloat16"`` is the
 control: the same call with the acceptance (and the observables) in
@@ -86,13 +80,6 @@ def inv_temp(temperature: float) -> np.float32:
     return np.float32(1.0 / float(temperature))
 
 
-def _to_float(bits):
-    """uint32 -> float32 rounded to nearest, through two exact halves."""
-    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
-    lo = (bits & 0xFFFF).astype(jnp.int32).astype(jnp.float32)
-    return hi * jnp.float32(65536.0) + lo
-
-
 def round_bf16(x):
     """float32 -> the nearest bfloat16 value (ties to even), kept as
     float32.  Done with integer ops: XLA on a TPU may run bfloat16
@@ -121,110 +108,74 @@ def neighbours(op, rows, is_black: bool):
 
 
 def _block_rows(n: int, width: int) -> int:
-    """Row-block height whose draws stay near 64 MiB per lane."""
+    """Row-block height whose temporaries of 4-byte cells stay near
+    64 MiB each."""
     rows = max(2, (1 << 24) // max(width, 1))
     while n % rows:
         rows //= 2
     return max(1, min(rows, n))
 
 
-def _by_blocks(fn, n: int, width: int):
-    """``fn(rows)`` over the row blocks of an n-row plane, stacked."""
+def by_blocks(fn, n: int, width: int):
+    """``fn(rows)`` over the row blocks of an n-row plane, stacked;
+    ``width`` is the cells a row of the block holds."""
     per = _block_rows(n, width)
     return jax.lax.map(
         lambda b: fn(b * per + jnp.arange(per, dtype=jnp.int32)),
         jnp.arange(n // per, dtype=jnp.int32))
 
 
-def _flips_site(t, nn, rows, beta, k0, k1, offset, width, precision):
-    cols = jnp.arange(t.shape[1], dtype=jnp.uint32)[None, :]
-    idx = rows.astype(jnp.uint32)[:, None] * jnp.uint32(width) + cols
-    zero = jnp.zeros_like(idx)
-    bits = philox(offset, zero, idx, zero, k0, k1)[0]
-    u = _to_float(bits) * jnp.float32(2.0 ** -32)
-    arg = jnp.float32(-2.0) * beta * nn.astype(jnp.float32) \
-        * t.astype(jnp.float32)
-    if precision == "bfloat16":
-        return round_bf16(u) < round_bf16(jnp.exp(round_bf16(arg)))
-    return u < jnp.exp(arg)
-
-
-def thresholds(beta, precision: str = "float32"):
-    """The 10 uint32 thresholds of the word stream, index 5 s + c."""
-    s = jnp.arange(2, dtype=jnp.float32)[:, None]
-    c = jnp.arange(5, dtype=jnp.float32)[None, :]
-    arg = jnp.float32(-2.0) * beta * (2.0 * s - 1.0) * (2.0 * c - 4.0)
-    if precision == "bfloat16":
-        p = round_bf16(jnp.exp(round_bf16(arg)))
-    else:
-        p = jnp.exp(arg)
-    scaled = (p * jnp.float32(2.0 ** 32)).astype(jnp.uint32)
-    return jnp.where(p < 1.0, scaled, jnp.uint32(0xFFFFFFFF)).reshape(10)
-
-
-def _flips_word(t, nn, rows, thr, k0, k1, offset, width):
-    words = t.shape[1] // 8
-    w = jnp.arange(words, dtype=jnp.uint32)[None, :]
-    idx = rows.astype(jnp.uint32)[:, None] * jnp.uint32(width // 8) + w
-    zero = jnp.zeros_like(idx)
-    off2 = _u32(offset) * jnp.uint32(2)
-    lanes = philox(off2, zero, idx, zero, k0, k1) \
-        + philox(off2 + jnp.uint32(1), zero, idx, zero, k0, k1)
-    draws = jnp.stack(lanes, axis=-1).reshape(t.shape)
-    s01 = (t.astype(jnp.int32) + 1) // 2
-    c01 = (nn.astype(jnp.int32) + 4) // 2
-    return draws < jnp.take(thr, 5 * s01 + c01)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("is_black", "stream", "precision"))
-def half_sweep(target, op, beta, k0, k1, offset, *, is_black: bool,
-               stream: str, precision: str = "float32"):
-    """One colour half-sweep of ``target`` (int8 +-1) against ``op``."""
-    n, width = target.shape
-    thr = thresholds(beta, precision) if stream == "word" else None
+def half_sweep(targets, ops, beta, k0, k1, offset, *, is_black: bool,
+               stream, precision: str = "float32"):
+    """One colour half-sweep of each lattice's ``targets`` plane (int8
+    +-1) against its ``ops`` plane; ``stream`` is the module that draws
+    a row block once for all the lattices and says which cells flip."""
+    n, width = targets[0].shape
 
     def block(rows):
-        t = jnp.take(target, rows, axis=0)
-        nn = neighbours(op, rows, is_black)
-        if stream == "site":
-            flip = _flips_site(t, nn, rows, beta, k0, k1, offset, width,
-                               precision)
-        else:
-            flip = _flips_word(t, nn, rows, thr, k0, k1, offset, width)
-        return jnp.where(flip, -t, t)
+        t = jnp.stack([jnp.take(p, rows, axis=0) for p in targets])
+        nn = jnp.stack([neighbours(p, rows, is_black) for p in ops])
+        flip = stream.flips(t, nn, rows, beta, k0, k1, offset, precision)
+        return tuple(jnp.where(flip, -t, t))
 
-    return _by_blocks(block, n, width).reshape(n, width)
+    return tuple(b.reshape(n, width)
+                 for b in by_blocks(block, n, len(targets) * width))
 
 
-def sweeps(black, white, *, temperature: float, seed: int, step0: int,
-           n_sweeps: int, stream: str, precision: str = "float32",
+def sweeps(blacks, whites, *, temperature: float, seed: int, step0: int,
+           n_sweeps: int, stream, precision: str = "float32",
            observe_every: int = 0):
-    """``n_sweeps`` sweeps from the cumulative sweep count ``step0``.
+    """``n_sweeps`` sweeps of the lattices whose colour planes are
+    ``blacks[i]``, ``whites[i]``, from the cumulative sweep count
+    ``step0``.
 
-    Returns ``(black, white, samples)``; with ``observe_every`` = j > 0,
-    every j-th sweep is followed by a sample ``(M, B)``: the sum of the
-    spins and the sum over bonds of s_i s_j, exact python ints (floats
-    accumulated in bfloat16 for the control)."""
+    Returns ``(blacks, whites, samples)``; with ``observe_every`` = j > 0,
+    every j-th sweep is followed by a sample: for each lattice ``(M,
+    B)``, the sum of the spins and the sum over bonds of s_i s_j, exact
+    python ints (floats accumulated in bfloat16 for the control)."""
     if precision not in PRECISIONS:
         raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
     beta = jnp.float32(inv_temp(temperature))
     k0, k1 = keys(seed)
+    blacks, whites = tuple(blacks), tuple(whites)
     samples = []
     for s in range(n_sweeps):
         for colour in (0, 1):
             off = np.uint32((2 * (step0 + s) + colour) % 2 ** 32)
             if colour == 0:
-                black = half_sweep(black, white, beta, k0, k1, off,
-                                   is_black=True, stream=stream,
-                                   precision=precision)
+                blacks = half_sweep(blacks, whites, beta, k0, k1, off,
+                                    is_black=True, stream=stream,
+                                    precision=precision)
             else:
-                white = half_sweep(white, black, beta, k0, k1, off,
-                                   is_black=False, stream=stream,
-                                   precision=precision)
+                whites = half_sweep(whites, blacks, beta, k0, k1, off,
+                                    is_black=False, stream=stream,
+                                    precision=precision)
         if observe_every and (s + 1) % observe_every == 0:
-            samples.append(observables(black, white, precision))
-    return black, white, samples
+            samples.append([observables(b, w, precision)
+                            for b, w in zip(blacks, whites)])
+    return blacks, whites, samples
 
 
 @jax.jit
@@ -244,13 +195,13 @@ def _row_sums(black, white):
                         axis=1, dtype=jnp.int32)
         return spins, bonds
 
-    spins, bonds = _by_blocks(block, n, width)
+    spins, bonds = by_blocks(block, n, width)
     return spins.reshape(n), bonds.reshape(n)
 
 
 def observables(black, white, precision: str = "float32"):
-    """(M, B): the sum of spins and the bond sum; exact integers, or in
-    bfloat16 accumulated row by row for the control."""
+    """(M, B) of one lattice: the sum of spins and the bond sum; exact
+    integers, or in bfloat16 accumulated row by row for the control."""
     spins, bonds = (np.asarray(x, np.int64) for x in _row_sums(black,
                                                                white))
     if precision == "float32":
@@ -262,53 +213,3 @@ def observables(black, white, precision: str = "float32"):
         m = bf(float(m) + float(bf(rs)))
         b = bf(float(b) + float(bf(rb)))
     return float(m), float(b)
-
-
-# -- layouts -----------------------------------------------------------------
-
-_SHIFTS = np.arange(8, dtype=np.uint32) * np.uint32(4)
-
-
-def _unpack(words):
-    v = (words[..., None] >> _SHIFTS) & jnp.uint32(0xF)
-    spins = 2 * v.astype(jnp.int32) - 1
-    return spins.reshape(words.shape[0], -1).astype(jnp.int8)
-
-
-@jax.jit
-def unpack_words(words):
-    """(n, W) uint32 words of 4-bit cells (cell 8w + j in bits 4j..4j+3,
-    value 0/1) -> (n, 8W) int8 spins 2v - 1; a cell holding anything but
-    0 or 1 comes out as neither -1 nor +1."""
-    n, w = words.shape
-    return _by_blocks(lambda rows: _unpack(jnp.take(words, rows, axis=0)),
-                      n, 8 * w).reshape(n, 8 * w)
-
-
-@jax.jit
-def pack_words(plane):
-    """(n, 8W) int8 spins +-1 -> (n, W) uint32 words of 4-bit cells, the
-    inverse of :func:`unpack_words`."""
-    n, width = plane.shape
-
-    def block(rows):
-        v = (jnp.take(plane, rows, axis=0).astype(jnp.int32) + 1) // 2
-        v = v.astype(jnp.uint32).reshape(rows.shape[0], width // 8, 8)
-        return jnp.sum(v << _SHIFTS, axis=-1, dtype=jnp.uint32)
-
-    return _by_blocks(block, n, width).reshape(n, width // 8)
-
-
-@functools.partial(jax.jit, static_argnames=("layout",))
-def count_differ(ref, got, layout: str = "int8"):
-    """Cells of the int8 plane ``ref`` that differ from ``got``, which is
-    held in ``layout``: ``int8`` (the same plane) or ``words4``."""
-    n, width = ref.shape
-
-    def block(rows):
-        g = jnp.take(got, rows, axis=0)
-        if layout == "words4":
-            g = _unpack(g)
-        return jnp.sum(jnp.take(ref, rows, axis=0) != g, dtype=jnp.int32)
-
-    return jnp.sum(_by_blocks(block, n, width), dtype=jnp.int32)

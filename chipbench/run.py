@@ -9,8 +9,14 @@ One process runs one cell once.  A cell (a ``workloads`` entry of
 finds each by name and holds no cell of its own:
 
 * ``chipbench/configs/<config>.json``  -- the deployment: engine, lattice,
-  temperature, initial state, mesh, and the layout and random stream the
-  reference replays;
+  temperature, initial state, mesh, and the names of its state layout
+  and random stream;
+* ``chipbench/layouts/<layout>.py``    -- how the engine's state arrays
+  hold lattices: ``LATTICES`` (how many an array holds), ``hot_start(key,
+  n, m)``, ``plane(a, r)`` (lattice r as the reference's int8 +-1 plane),
+  ``put(a, r, plane)`` (its inverse) and ``count_differ(plane, a, r)``;
+* ``chipbench/streams/<stream>.py``    -- the random stream the reference
+  replays: ``flips(t, nn, rows, beta, k0, k1, offset, precision)``;
 * ``chipbench/traffic/<traffic>.json`` -- the run plan: ``sweep``
   (``Session.run(k)`` repeated) or ``measure`` (``Session.measure(plan)``
   repeated), with the limits of what its check compares;
@@ -23,10 +29,11 @@ start on the device from ``--seed`` in one jitted call, opens a
 ``Session`` on it, and runs the traffic's call twice so that every program is compiled or loaded
 from the persistent cache.  The window then repeats the call until
 ``--seconds`` have passed and blocks on the state; ``flips_per_ns`` is
-n * m * sweeps over the whole window.  One call, drawn from the seed, is
-bracketed by copies of the state; once the window has closed and the
-session is freed, ``chipbench/reference.py`` replays that call and the
-spins (and, for ``measure``, the observables) are compared.  A compile
+n * m * sweeps * lattices over the whole window.  One call, drawn from
+the seed, is bracketed by copies of the state; once the window has
+closed and the session is freed, ``chipbench/reference.py`` replays that
+call for every lattice the state holds, and the spins (and, for
+``measure``, the observables of each lattice) are compared.  A compile
 inside the window fails the run.  ``--trace 1`` runs the same window
 under the profiler and reports the per-layer metrics instead.
 
@@ -65,6 +72,12 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
 #: calls of the traffic made in set-up, before the window
 WARM_CALLS = 2
+#: int8 cells the reference replays at once: a 65536^2 lattice, the
+#: largest one-chip cell's; a state of more lattices is replayed in groups
+REPLAY_CELLS = 2 ** 32
+#: what a layout and a stream file define
+LAYOUT_NAMES = ("LATTICES", "hot_start", "plane", "put", "count_differ")
+STREAM_NAMES = ("flips",)
 
 
 class CellError(Exception):
@@ -83,21 +96,35 @@ def _load_json(path: Path, what: str) -> dict:
         raise CellError(f"{what}: no file {path}") from None
 
 
-def _load_reader(path: Path, name: str):
+def _plugin(root: Path, kind: str, name: str, what: str) -> Path:
+    """The file of ``chipbench/<kind>/`` found by ``name``."""
+    path = root / "chipbench" / kind / f"{name}.py"
     if not path.is_file():
-        raise CellError(f"per-layer metric {name!r}: no reader {path}")
+        raise CellError(f"{what} {name!r}: no file {path}")
+    return path
+
+
+def _load_plugin(path: Path, what: str, names):
+    """The module at ``path``; it has to define every one of ``names``."""
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
+        "chipbench_" + "_".join(path.parts[-2:]).replace(".", "_")
+        .replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    if not callable(getattr(mod, "read", None)):
-        raise CellError(f"per-layer metric {name!r}: {path} has no read()")
-    return mod.read
+    missing = [n for n in names if not hasattr(mod, n)]
+    if missing:
+        raise CellError(f"{what}: {path} has no {', '.join(missing)}")
+    return mod
+
+
+def _load_reader(path: Path, name: str):
+    return _load_plugin(path, f"per-layer metric {name!r}", ("read",)).read
 
 
 def load_cell(name: str, root: Path = ROOT) -> dict:
     """The cell ``name`` of ``root/BENCHMARK.json`` with every file it
-    names loaded: config, traffic, and a reader per per-layer metric."""
+    names loaded: config, traffic, the config's state layout and random
+    stream, and a reader per per-layer metric."""
     bench = _load_json(root / "BENCHMARK.json", "benchmark")
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -119,12 +146,16 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
-    readers = {m["name"]: _load_reader(
-        root / "chipbench" / "metrics" / f"{m['name']}.py", m["name"])
-        for m in per_layer}
+    # every file is found before any is loaded
+    layout = _plugin(root, "layouts", config["layout"], "state layout")
+    stream = _plugin(root, "streams", config["stream"], "random stream")
+    readers = {m["name"]: _plugin(root, "metrics", m["name"],
+                                  "per-layer metric") for m in per_layer}
     return {"name": name, "workload": w, "config": config,
             "traffic": traffic, "end_to_end": e2e, "per_layer": per_layer,
-            "readers": readers,
+            "readers": {k: _load_reader(p, k) for k, p in readers.items()},
+            "layout": _load_plugin(layout, "state layout", LAYOUT_NAMES),
+            "stream": _load_plugin(stream, "random stream", STREAM_NAMES),
             "peaks": _load_json(root / "chipbench" / "peaks.json",
                                 "peaks")}
 
@@ -144,44 +175,33 @@ def _spec(config: dict, seed: int):
             axis_names=tuple(mesh["axis_names"])))
 
 
-def _hot_start(config: dict, seed: int):
-    """The lattice of a hot start, drawn on the default device from the
+def _hot_start(config: dict, layout, seed: int):
+    """The lattices of a hot start, drawn on the default device from the
     seed in one jitted call, as the named state arrays of the config's
     engine: each spin +1 or -1 with probability 1/2."""
     import jax
-    import jax.numpy as jnp
     if config["init_p_up"] != 0.5:
         raise CellError(f"config {config['name']!r}: only a hot start "
                         "(init_p_up 0.5) is drawn")
-    n, m, layout = config["n"], config["m"], config["layout"]
+    n, m = config["n"], config["m"]
 
     @jax.jit
     def make(key):
-        planes = []
-        for k in jax.random.split(key):
-            if layout == "words4":
-                bits = jax.random.bits(k, (n, m // 16), jnp.uint32)
-                planes.append(bits & jnp.uint32(0x11111111))
-            else:
-                bits = jax.random.bits(k, (n, m // 2), jnp.uint8)
-                planes.append((2 * (bits & 1) - 1).astype(jnp.int8))
-        return planes
+        return [layout.hot_start(k, n, m) for k in jax.random.split(key)]
 
-    if layout not in ("words4", "int8"):
-        raise CellError(f"state layout {layout!r} is neither 'int8' nor "
-                        "'words4'")
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                              (seed >> 32) & 0x7FFFFFFF)
     return dict(zip(config["arrays"], make(key)))
 
 
-def _open(config: dict, seed: int):
+def _open(cell: dict, seed: int):
     """A ``Session`` at sweep 0 on the hot start: the runner and engine
     are built as ``Session.restore`` builds them, with the arrays handed
     over on the device instead of read from a file."""
     from repro.api import Session
+    config = cell["config"]
     return Session._from_arrays(_spec(config, seed),
-                                _hot_start(config, seed), 0)
+                                _hot_start(config, cell["layout"], seed), 0)
 
 
 def _call(session, traffic: dict):
@@ -272,7 +292,7 @@ def _run(cell, seed, seconds, trace, control, trace_dir, log, devs,
     config, traffic = cell["config"], cell["traffic"]
     k = sweeps_per_call(traffic)
     t_open = time.perf_counter()
-    session = _open(config, seed)
+    session = _open(cell, seed)
     jax.block_until_ready(session.state)
     t_warm = time.perf_counter()
     for _ in range(WARM_CALLS):
@@ -332,7 +352,7 @@ def _run(cell, seed, seconds, trace, control, trace_dir, log, devs,
 
     elapsed = t_end - t_first
     sweeps = calls * k
-    flips = sweeps * config["n"] * config["m"]
+    flips = sweeps * config["n"] * config["m"] * cell["layout"].LATTICES
     peak_mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in used)
     step0 = (WARM_CALLS + checked) * k
@@ -340,8 +360,7 @@ def _run(cell, seed, seconds, trace, control, trace_dir, log, devs,
     gc.collect()
 
     t_ref = time.perf_counter()
-    checks = _checks(config, traffic, seed, step0, snaps, samples,
-                     control, used[0])
+    checks = _checks(cell, seed, step0, snaps, samples, control, used[0])
     checks["compiles_in_window"] = {"value": compiles.n, "limit": 0}
     log(f"reference replay of call {checked} (sweeps {step0}..{step0 + k}) "
         f"took {time.perf_counter() - t_ref:.1f} s")
@@ -395,60 +414,86 @@ def _reduce_trace(tmp: str, keep) -> dict:
     return ctrace.reduce(ev)
 
 
-def _planes(state, layout: str, device):
-    """The program's state as the reference's two int8 +-1 planes, on
-    one device."""
+def _groups(lattices: int, cells: int):
+    """The lattice indices the reference replays together, at most
+    ``REPLAY_CELLS`` int8 cells at a time (``cells`` to a lattice)."""
+    per = max(1, min(lattices, REPLAY_CELLS // cells))
+    return [range(r, min(r + per, lattices))
+            for r in range(0, lattices, per)]
+
+
+def _gap(got, want) -> float:
+    """Largest |got - want| over samples and lattices; ``got`` is the
+    program's (samples,) or (samples, lattices) array, ``want`` the
+    reference's (samples, lattices).  inf where the shapes disagree."""
+    got = np.asarray(got, np.float64)
+    if got.ndim not in (1, 2) or got.shape[0] != want.shape[0] \
+            or got.size != want.size:
+        return float("inf")
+    return max(abs(float(g) - float(w)) for g, w in
+               zip(got.reshape(want.shape).flat, want.flat))
+
+
+def _checks(cell, seed, step0, snaps, samples, control, device) -> dict:
+    """Replay the checked call with the reference, every lattice the
+    state holds; every compared number with its limit.  ``snaps`` holds
+    the state copies taken ``before`` and ``after`` the call; each is
+    dropped once used, so that the replay of a large lattice fits beside
+    them."""
     import jax
 
     from chipbench import reference as ref
-    planes = [jax.device_put(a, device) for a in state]
-    if layout == "words4":
-        planes = [ref.unpack_words(a) for a in planes]
-    return planes
-
-
-def _checks(config, traffic, seed, step0, snaps, samples, control,
-            device) -> dict:
-    """Replay the checked call with the reference; every compared
-    number with its limit.  ``snaps`` holds the state copies taken
-    ``before`` and ``after`` the call; each is dropped once used, so
-    that the replay of a large lattice fits beside them."""
-    import jax
-
-    from chipbench import reference as ref
-    k = sweeps_per_call(traffic)
-    layout = config["layout"]
-    b0, w0 = _planes(snaps.pop("before"), layout, device)
+    config, traffic, layout = cell["config"], cell["traffic"], cell["layout"]
     every = traffic["sweeps_between"] if traffic["kind"] == "measure" \
         else 0
     kw = dict(temperature=config["temperature"], seed=seed, step0=step0,
-              n_sweeps=k, stream=config["stream"], observe_every=every)
+              n_sweeps=sweeps_per_call(traffic), stream=cell["stream"],
+              observe_every=every)
     n = config["n"] * config["m"]
+    before = [jax.device_put(a, device) for a in snaps.pop("before")]
     if control:
         # the reference in bfloat16 stands in for the program's call,
         # its result held in the program's layout
         del snaps["after"]
-        cb, cw, csamp = ref.sweeps(b0, w0, precision="bfloat16", **kw)
-        got = [cb, cw] if layout == "int8" else [ref.pack_words(cb),
-                                                 ref.pack_words(cw)]
-        del cb, cw
-        if every:
-            samples = {"m": np.float32([m / n for m, _ in csamp]),
-                       "e": np.float32([-b / n for _, b in csamp])}
+        got, csamp = before, []
     else:
         got = [jax.device_put(a, device) for a in snaps.pop("after")]
-    rb, rw, rsamp = ref.sweeps(b0, w0, **kw)
-    differ = int(ref.count_differ(rb, got[0], layout)) + int(
-        ref.count_differ(rw, got[1], layout))
+    differ, rsamp = 0, []
+    groups = _groups(layout.LATTICES, n)
+    for group in groups:
+        b0, w0 = ([layout.plane(a, r) for r in group] for a in before)
+        if group is groups[-1]:
+            del before
+        if control:
+            cb, cw, cs = ref.sweeps(b0, w0, precision="bfloat16", **kw)
+            for i, r in enumerate(group):
+                got = [layout.put(got[0], r, cb[i]),
+                       layout.put(got[1], r, cw[i])]
+            del cb, cw
+            csamp.append(cs)
+        rb, rw, rs = ref.sweeps(b0, w0, **kw)
+        del b0, w0
+        differ += sum(int(layout.count_differ(rb[i], got[0], r))
+                      + int(layout.count_differ(rw[i], got[1], r))
+                      for i, r in enumerate(group))
+        del rb, rw
+        rsamp.append(rs)
     checks = {"spins_differ": {"value": differ, "limit": 0}}
     if every:
+        # (samples, lattices) arrays, the groups side by side
+        def per_lattice(parts, which):
+            return np.array([[obs[which] for g in sample for obs in g]
+                             for sample in zip(*parts)], dtype=object)
+
+        big_m, big_b = per_lattice(rsamp, 0), per_lattice(rsamp, 1)
+        if control:
+            samples = {"m": np.float32(per_lattice(csamp, 0) / n),
+                       "e": np.float32(-per_lattice(csamp, 1) / n)}
         limits = traffic["limits"]
-        m_gap = max(abs(float(m) - big_m / n)
-                    for m, (big_m, _) in zip(samples["m"], rsamp))
-        e_gap = max(abs(float(e) + big_b / n)
-                    for e, (_, big_b) in zip(samples["e"], rsamp))
-        checks["m_gap"] = {"value": m_gap, "limit": limits["m_gap"]}
-        checks["e_gap"] = {"value": e_gap, "limit": limits["e_gap"]}
+        checks["m_gap"] = {"value": _gap(samples["m"], big_m / n),
+                           "limit": limits["m_gap"]}
+        checks["e_gap"] = {"value": _gap(samples["e"], -big_b / n),
+                           "limit": limits["e_gap"]}
     return checks
 
 
@@ -468,13 +513,14 @@ def main(argv=None) -> int:
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
 
+    # the layout and stream files import chipbench.reference
+    sys.path.insert(0, str(ROOT / "src"))
+    sys.path.insert(0, str(ROOT))
     try:
         cell = load_cell(args.workload)
     except CellError as e:
         log(f"chipbench: {e}")
         return 2
-    sys.path.insert(0, str(ROOT / "src"))
-    sys.path.insert(0, str(ROOT))
     try:
         from repro import compile_cache
     except ImportError as e:

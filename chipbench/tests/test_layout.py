@@ -11,6 +11,7 @@ import pytest
 from chipbench import run
 
 ROOT = Path(__file__).resolve().parents[2]
+DATA = Path(__file__).resolve().parent / "data"
 
 
 def checkout(tmp_path):
@@ -68,40 +69,141 @@ def test_unknown_workload_fails_by_name():
         run.load_cell("no.such.cell")
 
 
-def test_new_cell_from_files_alone(tmp_path):
-    """A config, a traffic and a metric file plus one workload entry make
-    a cell; no harness code changes."""
+@pytest.mark.parametrize("kind,config", [
+    ("layout", "ising2d-stencil-65536x32768"),
+    ("stream", "ising2d-multispin-65536")])
+def test_missing_plugin_fails_by_name(tmp_path, kind, config, monkeypatch):
+    """A layout or stream with no file fails in load_cell, before any
+    JAX work."""
+    import jax
     root = checkout(tmp_path)
+    path = root / "chipbench" / "configs" / f"{config}.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    **{kind: "nibble-9"})))
+    workload = {w["config"]: w["name"] for w in json.loads(
+        (root / "BENCHMARK.json").read_text())["workloads"]}[config]
+
+    def no_jax(*args, **kwargs):
+        raise AssertionError("JAX work before the missing file was found")
+
+    monkeypatch.setattr(jax, "jit", no_jax)
+    monkeypatch.setattr(jax, "devices", no_jax)
+    with pytest.raises(run.CellError, match=rf"{kind}s/nibble-9\.py"):
+        run.load_cell(workload, root)
+
+
+#: the bitplane engine's layout and stream, as files a new cell brings
+BITPLANE = {"layouts/bits32.py": "layout_bits32.py",
+            "streams/site4.py": "stream_site4.py"}
+N, M = 64, 128
+
+
+def add_cell(root, case):
+    """Add, as new files and new entries alone, a cell of ``case``
+    (``multispin``, ``bitplane`` or ``bitplane.measure``) at 64 x 128
+    with a per-layer metric ``flips_per_sweep``; returns its name."""
     cb = root / "chipbench"
     cfg = json.loads((cb / "configs" / "ising2d-multispin-32768.json")
                      .read_text())
-    cfg.update(name="ising2d-multispin-tiny", n=32, m=64)
-    (cb / "configs" / "ising2d-multispin-tiny.json").write_text(
-        json.dumps(cfg))
+    cfg.update(name=f"ising2d-{case}-tiny", n=N, m=M)
+    if case.startswith("bitplane"):
+        cfg.update(engine="bitplane_pallas", layout="bits32",
+                   stream="site4", arrays=["black_bits", "white_bits"])
+        for dst, src in BITPLANE.items():
+            shutil.copy(DATA / src, cb / dst)
+    (cb / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
     (cb / "traffic" / "sweep-k2.json").write_text(json.dumps(
         {"kind": "sweep", "sweeps_per_call": 2}))
-    (cb / "metrics" / "calls_per_s.py").write_text(
-        "def read(ctx):\n    return ctx['calls'] / ctx['window_s']\n")
+    (cb / "metrics" / "flips_per_sweep.py").write_text(
+        "def read(ctx):\n    return ctx['flips'] / ctx['sweeps']\n")
+    name = f"tiny.{case}"
 
     def add(bench):
         bench["configs"].append(
-            {"name": "ising2d-multispin-tiny", "source": "test",
-             "file": "chipbench/configs/ising2d-multispin-tiny.json",
+            {"name": cfg["name"], "source": "test",
+             "file": f"chipbench/configs/{cfg['name']}.json",
              "reduced": ["n", "m"], "why": "test"})
         bench["workloads"].append(
-            {"name": "tiny.k2", "config": "ising2d-multispin-tiny",
-             "traffic": "sweep-k2", "chips": 1, "why": "test"})
+            {"name": name, "config": cfg["name"],
+             "traffic": "measure-e1" if case.endswith("measure")
+             else "sweep-k2", "chips": 1, "why": "test"})
         bench["per_layer"].append(
-            {"name": "calls_per_s", "unit": "1/s", "better": "higher",
-             "source": "host_clock", "layer": "facade and engines",
-             "moves": "flips_per_ns", "workloads": ["tiny.k2"]})
+            {"name": "flips_per_sweep", "unit": "1/sweep",
+             "better": "higher", "source": "host_clock",
+             "layer": "harness", "moves": "flips_per_ns",
+             "workloads": [name]})
     edit_bench(root, add)
-    cell = run.load_cell("tiny.k2", root)
-    assert cell["traffic"]["sweeps_per_call"] == 2
-    r = run.run_cell(cell, 5, 0.3, trace=True, require_chip=False,
-                     log=lambda msg: None)
+    return name
+
+
+def run_new(root, name, **kw):
+    return run.run_cell(run.load_cell(name, root), 5, 0.3,
+                        require_chip=False, log=lambda msg: None, **kw)
+
+
+@pytest.mark.parametrize("case,lattices", [
+    ("multispin", 1), ("bitplane", 32), ("bitplane.measure", 32)])
+def test_new_cell_from_files_alone(tmp_path, case, lattices):
+    """A config, a traffic, a metric file (and, for bitplane, a layout
+    and a stream file) plus new entries in BENCHMARK.json make a cell; no
+    harness code changes.  Flips count every lattice the state holds."""
+    root = checkout(tmp_path)
+    name = add_cell(root, case)
+    r = run_new(root, name, trace=True)
     assert r["correct"] is True, r["checks"]
-    assert r["metrics"]["calls_per_s"]["value"] > 0
+    assert r["checks"]["spins_differ"]["value"] == 0
+    assert r["metrics"]["flips_per_sweep"]["value"] == N * M * lattices
+    if case.endswith("measure"):
+        assert set(r["checks"]) >= {"m_gap", "e_gap"}
+
+
+#: lattices the reference replays together: all 32, or groups of 5 (the
+#: last of 2), as a state too large to replay at once is
+GROUPS = [32, 5]
+
+
+@pytest.mark.parametrize("case", ["bitplane", "bitplane.measure"])
+def test_new_bitplane_cell_in_groups(tmp_path, case, monkeypatch):
+    monkeypatch.setattr(run, "REPLAY_CELLS", N * M * 5)
+    root = checkout(tmp_path)
+    r = run_new(root, add_cell(root, case))
+    assert r["correct"] is True, r["checks"]
+
+
+@pytest.mark.parametrize("per", GROUPS)
+@pytest.mark.parametrize("case", ["bitplane", "bitplane.measure"])
+def test_new_bitplane_cell_control_is_not_correct(tmp_path, case, per,
+                                                  monkeypatch):
+    monkeypatch.setattr(run, "REPLAY_CELLS", N * M * per)
+    root = checkout(tmp_path)
+    r = run_new(root, add_cell(root, case), control=True)
+    assert r["correct"] is False
+    assert r["checks"]["spins_differ"]["value"] > 0
+
+
+@pytest.mark.parametrize("per", GROUPS)
+def test_new_bitplane_cell_catches_one_replica(tmp_path, per, monkeypatch):
+    """Cells of replica 5 alone flipped where the sweep returns: the
+    check counts each of them, and no other."""
+    import jax.numpy as jnp
+
+    from repro.core.engine import CounterEngine
+    planted = [(0, 3, 1), (0, 10, 7), (1, 0, 0)]
+    orig = CounterEngine.sweep_fn
+
+    def sweep_fn(self, state, inv_temp, seed, start_offset, n_sweeps):
+        new = list(orig(self, state, inv_temp, seed, start_offset,
+                        n_sweeps))
+        for c, i, k in planted:
+            new[c] = new[c].at[i, k].set(new[c][i, k] ^ jnp.uint32(1 << 5))
+        return tuple(new)
+
+    monkeypatch.setattr(CounterEngine, "sweep_fn", sweep_fn)
+    monkeypatch.setattr(run, "REPLAY_CELLS", N * M * per)
+    root = checkout(tmp_path)
+    r = run_new(root, add_cell(root, "bitplane"))
+    assert r["correct"] is False
+    assert r["checks"]["spins_differ"]["value"] == len(planted)
 
 
 def test_result_line_schema():

@@ -49,18 +49,20 @@ elif fault != "none":
         return run, sharding
     dist.make_packed_ising_step = faulty
 r = run.run_cell(cell, 2 ** 31 + 5, 0.5, trace={trace},
-                 require_chip=False, log=lambda msg: None)
+                 control={control}, require_chip=False,
+                 log=lambda msg: None)
 print(json.dumps(r))
 """
 
 
-def _run(fault, trace=False):
+def _run(fault, trace=False, control=False):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     p = subprocess.run(
         [sys.executable, "-c", CHILD.format(root=str(ROOT),
                                             src=str(ROOT / "src"),
-                                            fault=fault, trace=trace)],
+                                            fault=fault, trace=trace,
+                                            control=control)],
         env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
@@ -80,3 +82,9 @@ def test_sharded_traced_run():
     r = _run("none", trace=True)
     assert r["correct"] is True, r["checks"]
     assert r["metrics"]["dispatches_per_sweep"]["value"] == 0.125
+
+
+def test_sharded_control_is_not_correct():
+    r = _run("none", control=True)
+    assert r["correct"] is False
+    assert r["checks"]["spins_differ"]["value"] > 0
